@@ -2,8 +2,9 @@
 
 Sweeps Pallas block/tile candidates per (op, shape-bucket) — always
 including the kernel's hard-coded default, so the winning config is never
-slower than the default by construction — and records achieved time vs
-the `repro.roofline.analysis` single-kernel peak model. Winning configs
+slower than the default by construction — and, on a chip whose peaks are
+in `repro.roofline.analysis.KERNEL_PEAKS`, records achieved time vs that
+single-kernel peak model (the roofline columns are null elsewhere). Winning configs
 land in `src/repro/kernels/tuning_cache.json` (``--write-cache``), the
 committed table `kernels.tuning` serves at trace time when tuning is
 enabled; measured rows land in `results/BENCH_kernel_autotune.json`
@@ -29,7 +30,11 @@ import jax.numpy as jnp
 
 from benchmarks.common import er_graph, write_bench_json
 from repro.kernels import cutbatch, cutvals, fused_layer, mixer, phase, tuning
-from repro.roofline.analysis import achieved_fraction, kernel_bound_s
+from repro.roofline.analysis import (
+    KERNEL_PEAKS,
+    achieved_fraction,
+    kernel_bound_s,
+)
 
 SUITE = "kernel_autotune"
 
@@ -66,7 +71,10 @@ def _sweep(op, dim, call, candidates, flops, nbytes, repeats, backend):
     default_s = results[0][0]
     tuned_s, best = min(results, key=lambda r: r[0])
     bucket = tuning.shape_bucket(dim)
-    bound = kernel_bound_s(flops, nbytes, backend)
+    # the roofline columns exist only on a chip with published peaks
+    kind = jax.devices()[0].device_kind
+    bound = (kernel_bound_s(flops, nbytes, kind)
+             if kind in KERNEL_PEAKS else None)
     cfg_str = ";".join(f"{k}={v}" for k, v in sorted(best.items()))
     row = {
         "name": f"{SUITE}/{op}_{bucket}",
@@ -82,7 +90,8 @@ def _sweep(op, dim, call, candidates, flops, nbytes, repeats, backend):
         "flops": flops,
         "bytes_accessed": nbytes,
         "model_bound_s": bound,
-        "achieved_frac": achieved_fraction(flops, nbytes, tuned_s, backend),
+        "achieved_frac": (achieved_fraction(flops, nbytes, tuned_s, kind)
+                          if bound is not None else None),
         "derived": f"{cfg_str};default_s={default_s:.3e};bucket={bucket}",
     }
     return row, (tuning.cache_key(op, dim, backend), best)

@@ -51,7 +51,7 @@ def run(n_qubits: int = 16, repeats: int = 3):
         "derived": f"GFLOPs={flops / t / 1e9:.2f}",
     })
 
-    spins = jax.random.rademacher(key, (256, 512), jnp.float32) if hasattr(jax.random, "rademacher") else (jax.random.bernoulli(key, 0.5, (256, 512)).astype(jnp.float32) * 2 - 1)
+    spins = jax.random.rademacher(key, (256, 512), jnp.float32)
     g2 = er_graph(512, 0.2, seed=1)
     adj = g2.dense_adjacency()
     cb = jax.jit(lambda s: ref.cut_batch_dense(s, adj, g2.total_weight()))

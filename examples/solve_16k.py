@@ -65,11 +65,16 @@ if args.mesh:
     mesh_spec = parse_mesh_spec(args.mesh)
     compat.ensure_host_device_count(mesh_spec_size(mesh_spec))
 
+from repro import compat
 from repro.core import ParaQAOAConfig, solve, solve_distributed
 from repro.core.baselines import local_search
 from repro.core.graph import Graph
 from repro.kernels import tuning
+from repro.launch.mesh import device_info
 
+compat.use_compile_cache()
+dev = device_info()
+print(f"device: {dev['platform']} ({dev['kind']}) x{dev['count']}")
 if args.kernel_tuning:
     tuning.set_enabled(True)
 

@@ -229,7 +229,6 @@ _TRACING_WRAPPERS: dict[str, tuple] = {
     "jax.remat": (0, "fun"),
     "repro.compat.shard_map": (0, "f"),
     "jax.shard_map": (0, "f"),
-    "jax.experimental.shard_map.shard_map": (0, "f"),
     "jax.lax.scan": (0, "f"),
     "jax.lax.map": (0, "f"),
     "jax.lax.while_loop": (0, 1, "cond_fun", "body_fun"),

@@ -1,84 +1,52 @@
-"""Version-portability layer for JAX SPMD APIs.
+"""The one seam between the runtime and JAX's SPMD and platform APIs.
 
 The distributed runtime (core/distributed.py, launch/mesh.py,
-launch/sharding.py) must run unchanged on:
-
-  - stock JAX 0.4.x, where ``shard_map`` lives at
-    ``jax.experimental.shard_map.shard_map`` and takes ``check_rep=``;
-  - new-style JAX (>= 0.6), where it is ``jax.shard_map`` and the kwarg
-    was renamed ``check_vma=``;
-  - a laptop / CI runner with one physical CPU (via
-    ``--xla_force_host_platform_device_count`` host-device emulation) or a
-    real multi-device mesh.
-
-Everything version- or platform-conditional funnels through this module so
-call sites stay clean:
+launch/sharding.py) is written for the installed JAX (0.9) and runs on a
+real TPU mesh or on CPU host-device emulation
+(``--xla_force_host_platform_device_count``). Everything platform-
+conditional funnels through this module so call sites stay clean:
 
   ``shard_map(f, mesh, in_specs, out_specs, check=False)``
-      Resolved implementation with the check kwarg adapted.
+      ``jax.shard_map`` with ``check`` passed as ``check_vma``.
   ``jit(f, donate_argnums=...)``
       ``jax.jit`` that drops buffer donation on backends that do not
       implement it (CPU), avoiding per-call "donation not usable" warnings.
-  ``make_mesh(shape, axis_names)``
-      ``jax.make_mesh`` when present, else mesh_utils + Mesh.
+  ``make_mesh(shape, axis_names, devices=None)``
+      ``jax.make_mesh`` with every axis ``Auto``: the runtime's programs
+      state their shardings through shard_map specs, not sharding-in-types.
   ``ensure_host_device_count(n)``
       Idempotent CPU host-device emulation: appends the XLA flag if the
       backend is not yet initialized (no-op, with the actual count
       returned, when it is).
+  ``use_compile_cache()``
+      JAX's persistent compilation cache at a fixed path, for the drivers.
 
-See docs/TESTING.md for the support matrix.
+See docs/TESTING.md for how the CPU emulation is used in the tests.
 """
 
 from __future__ import annotations
 
 import functools
-import inspect
 import os
-from typing import Any, Callable, Sequence
+from typing import Callable, Sequence
 
 import jax
+from jax.sharding import AxisType
 
-JAX_VERSION: tuple = tuple(int(x) for x in jax.__version__.split(".")[:3])
+# <checkout>/src/repro/compat.py → <checkout>
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 # ------------------------------------------------------------- shard_map --
-def _resolve_shard_map() -> Callable:
-    sm = getattr(jax, "shard_map", None)  # new-style (jax >= 0.6)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm  # 0.4.x
-    return sm
-
-
-_RAW_SHARD_MAP: Callable = _resolve_shard_map()
-
-
-def _check_kwarg_name() -> str | None:
-    """'check_vma' (new), 'check_rep' (0.4.x), or None if neither exists."""
-    try:
-        params = inspect.signature(_RAW_SHARD_MAP).parameters
-    except (TypeError, ValueError):  # builtins / odd wrappers: be permissive
-        return None
-    for name in ("check_vma", "check_rep"):
-        if name in params:
-            return name
-    return None
-
-
-_CHECK_KWARG: str | None = _check_kwarg_name()
-
-
 def shard_map(f: Callable, mesh, in_specs, out_specs, *, check: bool = False):
-    """Portable shard_map. ``check`` maps onto check_vma/check_rep.
+    """``jax.shard_map`` with the replication check off by default.
 
-    The runtime disables replication/VMA checking by default: the merge
-    winner-select and top-k reductions produce values that *are* replicated
-    but that the static checkers of several JAX versions cannot prove so.
+    The merge winner-select and top-k reductions produce values that *are*
+    replicated but that the static checker cannot prove so.
     """
-    kwargs: dict = {}
-    if _CHECK_KWARG is not None:
-        kwargs[_CHECK_KWARG] = check
-    return _RAW_SHARD_MAP(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
     )
 
 
@@ -98,24 +66,24 @@ def jit(f: Callable, *, donate_argnums: Sequence[int] = (), **kwargs):
 
 
 # ------------------------------------------------------------------- mesh --
-def make_mesh(shape: Sequence[int], axis_names: Sequence[str]):
-    """Portable dense device mesh over the default backend's devices."""
-    mk = getattr(jax, "make_mesh", None)  # jax >= 0.4.35
-    if mk is not None:
-        return mk(tuple(shape), tuple(axis_names))
-    from jax.experimental import mesh_utils
-    from jax.sharding import Mesh
+def make_mesh(shape: Sequence[int], axis_names: Sequence[str],
+              devices: Sequence | None = None):
+    """Dense device mesh over ``devices`` (default: all of the default
+    backend's), every axis ``Auto``.
 
-    return Mesh(mesh_utils.create_device_mesh(tuple(shape)), tuple(axis_names))
+    ``jax.make_mesh`` alone makes ``Explicit`` axes, under which indexing a
+    sharded array raises unless each gather names its output sharding.
+    """
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(shape), names,
+                         axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)
 
 
 def _backend_initialized() -> bool:
-    try:
-        from jax._src import xla_bridge
+    from jax._src import xla_bridge
 
-        return bool(xla_bridge._backends)
-    except Exception:  # private API moved: assume initialized (conservative)
-        return True
+    return bool(xla_bridge._backends)
 
 
 _HOST_COUNT_FLAG = "--xla_force_host_platform_device_count"
@@ -236,3 +204,20 @@ def cached_program(builder: Callable) -> Callable:
         return _LedgerProgram(program, builder.__name__, repr(key))
 
     return functools.lru_cache(maxsize=_PROGRAM_CACHE_SIZE)(build)
+
+
+# ------------------------------------------------------ compilation cache --
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Call before the first compile. Where ``JAX_COMPILATION_CACHE_DIR`` is
+    set, JAX reads it itself and nothing is set here. Otherwise the cache
+    is ``<checkout>/.jax_cache``: a fixed path, because the path is part of
+    each entry's key and a directory that moves never hits.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

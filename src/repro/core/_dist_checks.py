@@ -34,8 +34,8 @@ def check_solve_pool():
     part = connectivity_preserving_partition(g, 6)
     cfg = qaoa_mod.QAOAConfig(n_qubits=11, p_layers=2, opt_steps=10, top_k=2)
     edges, weights, masks = qaoa_mod.pad_subgraph_arrays(part.subgraphs, 11)
-    # single-device reference
-    want = qaoa_mod.solve_subgraph_batch(edges, weights, masks, cfg)
+    # single-device reference: the compiled batch program `solve` runs
+    want = qaoa_mod.solve_subgraph_batch_program(cfg)(edges, weights, masks)
     got = dist.solve_pool(edges, weights, masks, cfg, mesh)
     return {
         "bitstrings_equal": bool(

@@ -95,14 +95,20 @@ def topk_marginal(re, im, n: int, real_mask, k: int):
 
     Padding qubits keep the statevector shape uniform across a vmapped
     subgraph batch; their amplitude mass is folded back onto the
-    pad-bits-zero representative via a masked-key segment sum so top-k never
-    returns duplicates that differ only in padding bits. ``real_mask`` is
+    pad-bits-zero representative so top-k never returns duplicates that
+    differ only in padding bits. ``real_mask`` is
     (2^n_real - 1) and may be traced (per-subgraph under vmap).
     """
-    probs = re * re + im * im
-    idx = jnp.arange(2**n, dtype=jnp.int32)
-    keys = idx & real_mask
-    marg = jnp.zeros_like(probs).at[keys].add(probs)
+    marg = re * re + im * im
+    # fold each padding qubit (a zero bit of the mask) onto its 0 value,
+    # highest first: fixed-order elementwise adds, where a scatter-add's
+    # order (and so its rounding) may change with the batch it runs in
+    for q in reversed(range(n)):
+        v = marg.reshape(-1, 2, 2**q)
+        folded = jnp.concatenate(
+            [v[:, :1] + v[:, 1:], jnp.zeros_like(v[:, 1:])], axis=1
+        ).reshape(-1)
+        marg = jnp.where((real_mask >> q) & 1, marg, folded)
     vals, inds = jax.lax.top_k(marg, k)
     return inds, vals
 

@@ -20,6 +20,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import tuning
+from repro.kernels.mixer import F32_DOT
 
 BATCH_TILE = 128
 K_CHUNK = 512
@@ -32,9 +33,7 @@ def _kernel(nk: int, wtot_ref, s_chunk_ref, a_ref, s_full_ref, out_ref, acc_ref)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        s_chunk_ref[...], a_ref[...], preferred_element_type=jnp.float32
-    )
+    acc_ref[...] += jnp.dot(s_chunk_ref[...], a_ref[...], **F32_DOT)
 
     @pl.when(kk == nk - 1)
     def _epilogue():

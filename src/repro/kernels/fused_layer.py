@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import tuning
-from repro.kernels.mixer import rx_group_mats
+from repro.kernels.mixer import F32_DOT, rx_group_mats
 
 ROW_TILE = 512
 
@@ -45,7 +45,6 @@ def _kernel(k: int, reverse: bool, g_ref, b_ref, c_ref, re_ref, im_ref,
     cs = jnp.cos(gamma * cv)
     sn = jnp.sin(gamma * cv)
     cmat, dmat = rx_group_mats(b_ref[0, 0], k)
-    f32 = jnp.float32
 
     re = re_ref[...]
     im = im_ref[...]
@@ -55,10 +54,8 @@ def _kernel(k: int, reverse: bool, g_ref, b_ref, c_ref, re_ref, im_ref,
 
     def mixer(pr, pi):
         return (
-            jnp.dot(pr, cmat, preferred_element_type=f32)
-            - jnp.dot(pi, dmat, preferred_element_type=f32),
-            jnp.dot(pi, cmat, preferred_element_type=f32)
-            + jnp.dot(pr, dmat, preferred_element_type=f32),
+            jnp.dot(pr, cmat, **F32_DOT) - jnp.dot(pi, dmat, **F32_DOT),
+            jnp.dot(pi, cmat, **F32_DOT) + jnp.dot(pr, dmat, **F32_DOT),
         )
 
     if reverse:

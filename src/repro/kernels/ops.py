@@ -18,8 +18,11 @@ phase is a rotation by γ·c; the mixer-group generator is even in β on its
 real part and odd on its imaginary part), so every backward pass re-enters
 the same dispatch with negated angles — the gradient trace runs whatever
 implementation the forward ran, and the ascent loops in core/engine.py and
-core/qaoa.py need no `using_implementation("xla")` pin. The residual
-angle/cut-value gradients are cheap elementwise reductions left to XLA.
+core/qaoa.py need no `using_implementation("xla")` pin. The angle
+gradients are Σ a·b reductions through the dispatched `_vdot` (the
+`phase.vdot` kernel on the Pallas path, whose fixed summation order keeps
+a subgraph's gradient independent of its vmap batch); the cut-value
+cotangents are elementwise.
 """
 
 from __future__ import annotations
@@ -97,7 +100,7 @@ def _f32(x):
 # the cotangents are plain masked reductions of the output cotangent:
 #   d_w[e]   = Σ_b g[b] · xor_e(b)
 #   d_lin[v] = Σ_b g[b] · bit_v(b)
-# — cheap elementwise reductions left to XLA, per the PR 9 convention.
+# — reductions left to XLA (no solve path differentiates the weights).
 # Integer primals (edges, idx) get float0 symbolic-zero cotangents.
 # ---------------------------------------------------------------------------
 
@@ -235,6 +238,18 @@ def cutvals_at(idx, edges, weights, linear=None):
 # apply_phase — diagonal cost rotation, VJP = same rotation at −γ
 # ---------------------------------------------------------------------------
 
+def _vdot(a, b):
+    """Σ a·b — the angle-gradient reductions of the VJP rules below. On
+    the Pallas path the kernel fixes the summation order, so a subgraph's
+    gradient is the same whatever batch it is vmapped in."""
+    p = _pallas()
+    if p["use"]:
+        from repro.kernels import phase as k
+
+        return k.vdot(a, b, interpret=p["interpret"])
+    return ref.vdot(a, b)
+
+
 def _phase_dispatch(re, im, cutv, gamma):
     p = _pallas()
     if p["use"]:
@@ -260,7 +275,7 @@ def _phase_bwd(res, cot):
     # the rotation's transpose is the rotation at −γ: same dispatched kernel
     g_re, g_im = _phase_dispatch(d_ore, d_oim, cutv, -gamma)
     t = im * g_re - re * g_im
-    d_gamma = jnp.sum(cutv * t)
+    d_gamma = _vdot(cutv, t)
     d_cutv = gamma * t
     return g_re, g_im, d_cutv, d_gamma
 
@@ -318,7 +333,7 @@ def _mixer_bits_bwd(n, lo_bit, nbits, res, cot):
     # d_beta = Σ d_ore·N(oim) − d_oim·N(ore)
     fr = _neighbor_sum_bits(ore, lo_bit, nbits)
     fi = _neighbor_sum_bits(oim, lo_bit, nbits)
-    d_beta = jnp.sum(d_ore * fi) - jnp.sum(d_oim * fr)
+    d_beta = _vdot(d_ore, fi) - _vdot(d_oim, fr)
     return g_re, g_im, d_beta
 
 
@@ -421,14 +436,14 @@ def _layer_bwd(n, group, res, cot):
     # (neighbor-sum over *all* qubits) runs on the layer output
     fr = _neighbor_sum_bits(ore, 0, n)
     fi = _neighbor_sum_bits(oim, 0, n)
-    d_beta = jnp.sum(d_ore * fi) - jnp.sum(d_oim * fr)
+    d_beta = _vdot(d_ore, fi) - _vdot(d_oim, fr)
     # state cotangent through the whole layer: reversed layer at (−γ, −β)
     g_re, g_im = _layer_adjoint_dispatch(n, group, d_ore, d_oim, cutv,
                                          gamma, beta)
     # ∂γ and ∂cutv fall out of the phase rule with (re, im) the layer
     # *input* (the phase's input) and g the fully back-propagated cotangent
     t = im * g_re - re * g_im
-    d_gamma = jnp.sum(cutv * t)
+    d_gamma = _vdot(cutv, t)
     d_cutv = gamma * t
     return g_re, g_im, d_cutv, d_gamma, d_beta
 

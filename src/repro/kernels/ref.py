@@ -149,6 +149,11 @@ def expectation(re, im, cutv):
     return jnp.sum((re * re + im * im) * cutv)
 
 
+def vdot(a, b):
+    """sum_b a_b * b_b of two flat real vectors."""
+    return jnp.sum(a * b)
+
+
 def cut_batch_dense(spins: jnp.ndarray, adjacency: jnp.ndarray, total_weight):
     """Cut values for ±1 spin assignments via dense matmul (MXU form).
 

@@ -67,6 +67,24 @@ def clamp_tile(dim: int, tile: int) -> int:
     return t
 
 
+LANES = 128  # TPU vector lane width: the minor dimension of a lane-dense block
+
+
+def lane_view(count: int, tile: int) -> Tuple[int, int, int]:
+    """(rows, lanes, tile_rows) of the lane-dense 2-D view of a flat
+    vector of ``count`` elements blocked ``tile`` elements at a time
+    (``tile`` divides ``count`` and is a multiple of the lane count).
+
+    A 2-D block keeps its last two dimensions legal for the TPU compiler
+    (full dims, or multiples of (8, 128)) when `jax.vmap` prepends a batch
+    axis; a 1-D block would not be. Tiles shorter than one lane row use
+    the tile as the row width.
+    """
+    lanes = min(LANES, tile)
+    assert count % tile == 0 and tile % lanes == 0, (count, tile)
+    return count // lanes, lanes, tile // lanes
+
+
 def pad_chunks(count: int, chunk: int) -> int:
     """Pad a count up to a chunk multiple, with at least one full chunk
     (the edge-list case: padding entries are zero-weight no-ops)."""

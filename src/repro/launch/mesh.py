@@ -3,8 +3,8 @@
 Defined as functions (never module-level constants) so importing this module
 never touches jax device state — required because the dry-run (and the CPU
 host-device emulation in repro.compat) must set XLA_FLAGS before any jax
-initialization. All construction goes through `repro.compat.make_mesh` so
-the same code runs on jax versions with and without `jax.make_mesh`.
+initialization. All construction goes through `repro.compat.make_mesh`, so
+every mesh has `Auto` axes.
 
 The CLI mesh spec is a comma-separated `axis=size` list, e.g.
 ``data=8``, ``data=2,model=4``, ``pod=2,data=16,model=16``. Axis names are
@@ -74,32 +74,47 @@ def mesh_spec_size(spec: dict) -> int:
     return total
 
 
+def too_few_devices(what: str, need: int, have: int) -> str:
+    """Error text for a mesh that needs more devices than are visible.
+
+    Only the CPU backend can emulate more devices, so only there does the
+    text advise the host-device flag.
+    """
+    import jax
+
+    msg = f"{what} needs {need} devices but only {have} are visible"
+    if jax.default_backend() == "cpu":
+        msg += (f" — set XLA_FLAGS=--xla_force_host_platform_device_count="
+                f"{need} (or call compat.ensure_host_device_count before jax "
+                "initializes)")
+    return msg
+
+
+def device_info() -> dict:
+    """Platform, device kind and device count of the default backend."""
+    import jax
+
+    devices = jax.devices()
+    return {"platform": devices[0].platform, "kind": devices[0].device_kind,
+            "count": len(devices)}
+
+
 def build_mesh(spec: dict):
     """Device mesh for a parsed spec, over the first prod(sizes) devices.
 
-    Unlike `compat.make_mesh` (which uses *all* visible devices), this
-    tolerates a backend exposing more devices than the spec asks for —
+    Tolerates a backend exposing more devices than the spec asks for —
     the CLI case where `ensure_host_device_count` found the backend
     already initialized with a larger emulated count.
     """
     import jax
-    import numpy as np
-    from jax.sharding import Mesh
 
-    shape = tuple(spec.values())
-    names = tuple(spec.keys())
     total = mesh_spec_size(spec)
     devices = jax.devices()
     if len(devices) < total:
-        raise ValueError(
-            f"mesh spec {spec} needs {total} devices but only "
-            f"{len(devices)} are visible — set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={total} "
-            "(or call compat.ensure_host_device_count before jax initializes)"
-        )
-    if len(devices) == total:
-        return compat.make_mesh(shape, names)
-    return Mesh(np.asarray(devices[:total]).reshape(shape), names)
+        raise ValueError(too_few_devices(f"mesh spec {spec}", total,
+                                         len(devices)))
+    return compat.make_mesh(tuple(spec.values()), tuple(spec.keys()),
+                            devices=devices[:total])
 
 
 def make_production_mesh(*, multi_pod: bool = False):

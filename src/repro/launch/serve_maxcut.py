@@ -122,21 +122,28 @@ def run(argv=None):
         # parse + emulate *before* the first jax backend touch (graph
         # construction below creates device arrays)
         from repro import compat
-        from repro.launch.mesh import mesh_spec_size, parse_mesh_spec
+        from repro.launch.mesh import (
+            mesh_spec_size,
+            parse_mesh_spec,
+            too_few_devices,
+        )
 
         mesh_spec = parse_mesh_spec(args.mesh)
         need = mesh_spec_size(mesh_spec)
         have = compat.ensure_host_device_count(need)
         if have < need:
-            raise SystemExit(
-                f"--mesh {args.mesh} needs {need} devices but the jax "
-                f"backend is already up with {have}; set XLA_FLAGS="
-                f"--xla_force_host_platform_device_count={need}"
-            )
+            raise SystemExit(too_few_devices(f"--mesh {args.mesh}", need, have))
 
+    from repro import compat
+    from repro.launch.mesh import device_info
     from repro.obs.trace import Tracer
     from repro.service import SLA, ServiceConfig, SolveService
     from repro.service.workload import problem_mix, tenant_mix
+
+    compat.use_compile_cache()
+    dev = device_info()
+    print(f"[serve_maxcut] device: {dev['platform']} ({dev['kind']}) "
+          f"x{dev['count']}")
 
     requests = problem_mix(
         args.requests, (args.n_min, args.n_max), args.p,

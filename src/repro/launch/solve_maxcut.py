@@ -102,22 +102,23 @@ def run(argv=None):
         # parse + emulate *before* the first jax backend touch (graph
         # construction below creates device arrays)
         from repro import compat
-        from repro.launch.mesh import mesh_spec_size, parse_mesh_spec
+        from repro.launch.mesh import (
+            mesh_spec_size,
+            parse_mesh_spec,
+            too_few_devices,
+        )
 
         mesh_spec = parse_mesh_spec(args.mesh)
         need = mesh_spec_size(mesh_spec)
         have = compat.ensure_host_device_count(need)
         if have < need:
-            raise SystemExit(
-                f"--mesh {args.mesh} needs {need} devices but the jax "
-                f"backend is already up with {have}; set XLA_FLAGS="
-                f"--xla_force_host_platform_device_count={need}"
-            )
+            raise SystemExit(too_few_devices(f"--mesh {args.mesh}", need, have))
 
     import contextlib
 
     import numpy as np
 
+    from repro import compat
     from repro.core import ParaQAOAConfig, solve, solve_distributed
     from repro.core.graph import (
         Graph,
@@ -125,8 +126,13 @@ def run(argv=None):
         independent_set_violations,
     )
     from repro.core.pei import pei
+    from repro.launch.mesh import device_info
     from repro.obs.trace import Tracer, use_tracer
 
+    compat.use_compile_cache()
+    dev = device_info()
+    print(f"[maxcut] device: {dev['platform']} ({dev['kind']}) "
+          f"x{dev['count']}")
     if args.weights == "uniform":
         graph = Graph.erdos_renyi_weighted(args.n, args.p, seed=args.seed)
     elif args.weights == "spin":

@@ -31,32 +31,41 @@ PEAK_FLOPS = 197e12  # bf16 / chip
 HBM_BW = 819e9  # B/s / chip
 ICI_BW = 50e9  # B/s / link
 
-# per-backend (peak_flops, memory_bandwidth) envelopes for single-kernel
-# bounds. The tpu row is the v5e chip above; the cpu row is a nominal
-# host envelope so the autotune harness's achieved-vs-peak column stays
-# defined on CPU/interpret sweeps — a scoreboard for relative tile
-# quality there, not silicon truth.
+# Per-chip (peak_flops, memory_bandwidth) envelopes for single-kernel
+# bounds, keyed by `jax.Device.device_kind`. Source: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s).
+# A device that is not in the table has no roofline: asking for one is an
+# error, never a fallback to another chip's peaks.
 KERNEL_PEAKS = {
-    "tpu": (PEAK_FLOPS, HBM_BW),
-    "cpu": (2.0e11, 5.0e10),
+    "TPU v5 lite": (PEAK_FLOPS, HBM_BW),
 }
 
 
+def kernel_peaks(device_kind: str) -> tuple:
+    """(peak FLOP/s, peak bytes/s) of one chip of ``device_kind``."""
+    try:
+        return KERNEL_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(KERNEL_PEAKS)}") from None
+
+
 def kernel_bound_s(flops: float, bytes_accessed: float,
-                   backend: str = "tpu") -> float:
-    """Roofline lower bound for one kernel launch on `backend`:
-    max(compute-limited, memory-limited) seconds."""
-    pf, pb = KERNEL_PEAKS.get(backend, KERNEL_PEAKS["tpu"])
+                   device_kind: str) -> float:
+    """Roofline lower bound for one kernel launch on one chip of
+    ``device_kind``: max(compute-limited, memory-limited) seconds."""
+    pf, pb = kernel_peaks(device_kind)
     return max(flops / pf, bytes_accessed / pb)
 
 
 def achieved_fraction(flops: float, bytes_accessed: float, seconds: float,
-                      backend: str = "tpu") -> float:
+                      device_kind: str) -> float:
     """bound/measured — 1.0 means the launch hit the peak model; the
     autotuner records this per (op, shape-bucket) candidate."""
     if seconds <= 0.0:
         return 0.0
-    return kernel_bound_s(flops, bytes_accessed, backend) / seconds
+    return kernel_bound_s(flops, bytes_accessed, device_kind) / seconds
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

@@ -1,18 +1,21 @@
-"""Unit tests for the JAX version-portability layer (repro.compat)."""
+"""Unit tests for the JAX seam (repro.compat)."""
+
+import inspect
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro import compat
 
 
 def test_shard_map_resolved():
-    assert callable(compat._RAW_SHARD_MAP)
-    # on every supported version exactly one of the two kwargs exists
-    assert compat._CHECK_KWARG in ("check_vma", "check_rep")
+    # the installed jax spells the replication check `check_vma`, the one
+    # kwarg the wrapper passes
+    params = inspect.signature(jax.shard_map).parameters
+    assert "check_vma" in params and "check_rep" not in params
 
 
 def test_shard_map_runs_on_single_device_mesh():
@@ -25,30 +28,25 @@ def test_shard_map_runs_on_single_device_mesh():
 
 
 def test_check_kwarg_adaptation(monkeypatch):
-    """The wrapper must translate `check=` onto whichever kwarg the
-    resolved shard_map exposes — both the new-style and 0.4.x spellings."""
-    seen = {}
+    """The wrapper must translate `check=` onto `check_vma=`, off unless
+    asked for."""
+    seen = []
 
-    def new_style(f, *, mesh, in_specs, out_specs, check_vma=True):
-        seen.update(check_vma=check_vma)
+    def fake(f, *, mesh, in_specs, out_specs, check_vma=True):
+        seen.append(check_vma)
         return f
 
-    def old_style(f, *, mesh, in_specs, out_specs, check_rep=True):
-        seen.update(check_rep=check_rep)
-        return f
-
-    for impl, kwarg in ((new_style, "check_vma"), (old_style, "check_rep")):
-        monkeypatch.setattr(compat, "_RAW_SHARD_MAP", impl)
-        assert compat._check_kwarg_name() == kwarg
-        monkeypatch.setattr(compat, "_CHECK_KWARG", kwarg)
-        seen.clear()
-        compat.shard_map(lambda x: x, None, in_specs=(), out_specs=())
-        assert seen == {kwarg: False}
+    monkeypatch.setattr(jax, "shard_map", fake)
+    compat.shard_map(lambda x: x, None, in_specs=(), out_specs=())
+    compat.shard_map(lambda x: x, None, in_specs=(), out_specs=(), check=True)
+    assert seen == [False, True]
 
 
 def test_make_mesh_axes():
     mesh = compat.make_mesh((1, 1), ("data", "model"))
     assert mesh.shape == {"data": 1, "model": 1}
+    # Auto axes: indexing an array sharded on the mesh stays a plain slice
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
     assert compat.mesh_data_axes(mesh) == ("data",)
     assert compat.mesh_model_axis(mesh) == "model"
     no_model = compat.make_mesh((1,), ("data",))

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import configs
+from repro import compat, configs
 from repro.models.model import build_model
 from repro.training import optimizer as opt
 from repro.training.checkpoint import CheckpointManager
@@ -130,7 +130,7 @@ import jax, json
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro import configs
+from repro import compat, configs
 from repro.models.model import build_model
 from repro.launch.sharding import params_shardings
 from repro.training.fault_tolerance import reshard_state
@@ -141,12 +141,12 @@ params = model.init(jax.random.PRNGKey(0))
 batch = {"tokens": jnp.arange(2 * 16, dtype=jnp.int32).reshape(2, 16) % cfg.vocab_size}
 want, _ = model.forward(params, batch)
 
-m1 = jax.make_mesh((2, 4), ("data", "model"))
+m1 = compat.make_mesh((2, 4), ("data", "model"))
 s1 = params_shardings(jax.eval_shape(lambda: params), cfg, m1)
 p1 = reshard_state(params, s1)
 got1, _ = jax.jit(lambda p, b: model.forward(p, b))(p1, batch)
 
-m2 = jax.make_mesh((4, 2), ("data", "model"))
+m2 = compat.make_mesh((4, 2), ("data", "model"))
 s2 = params_shardings(jax.eval_shape(lambda: params), cfg, m2)
 p2 = reshard_state(p1, s2)  # re-mesh from the *sharded* state
 got2, _ = jax.jit(lambda p, b: model.forward(p, b))(p2, batch)

@@ -91,6 +91,16 @@ def test_expectation_kernel_matches_ref(n):
     assert got == pytest.approx(want, rel=1e-5)
 
 
+@pytest.mark.parametrize("n", [3, 6, 12])
+def test_vdot_kernel_matches_ref(n):
+    k1, k2 = jax.random.split(jax.random.PRNGKey(n))
+    a = jax.random.normal(k1, (2**n,), jnp.float32)
+    b = jax.random.normal(k2, (2**n,), jnp.float32)
+    want = float(ref.vdot(a, b))
+    got = float(phase.vdot(a, b, interpret=True))
+    assert got == pytest.approx(want, rel=1e-5, abs=1e-5)
+
+
 # ------------------------------------------------------------------ mixer --
 @pytest.mark.parametrize("n", [3, 5, 8, 10])
 @pytest.mark.parametrize("beta", [0.1, 0.9, 2.5])

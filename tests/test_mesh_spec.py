@@ -82,3 +82,18 @@ def test_solver_cli_rejects_malformed_mesh():
         solve_maxcut.run(["--n", "16", "--mesh", "data=two"])
     with pytest.raises(ValueError):
         solve_maxcut.run(["--n", "16", "--mesh", "rows=4"])
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_device_shortage_advice_only_on_cpu(monkeypatch, backend):
+    """Only the CPU backend can emulate devices, so only there does the
+    error advise the host-device flag."""
+    import jax
+
+    from repro.launch.mesh import too_few_devices
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    msg = too_few_devices("--mesh data=4", 4, 1)
+    assert msg.startswith("--mesh data=4 needs 4 devices but only 1")
+    assert ("xla_force_host_platform_device_count" in msg) == (
+        backend == "cpu")

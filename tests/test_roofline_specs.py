@@ -112,3 +112,14 @@ def test_roofline_bottleneck_selection():
     )
     assert r.bottleneck == "compute"
     assert r.compute_s == pytest.approx(1.0)
+
+
+def test_kernel_peaks_keyed_by_device_kind():
+    # a v5e chip is memory-bound at 819 GB/s for a byte-heavy launch
+    assert RA.kernel_bound_s(0.0, 819e9, "TPU v5 lite") == pytest.approx(1.0)
+    assert RA.achieved_fraction(197e12, 0.0, 2.0, "TPU v5 lite") == (
+        pytest.approx(0.5))
+    # an unknown device has no roofline, never another chip's peaks
+    for kind in ("cpu", "TPU v4", "tpu"):
+        with pytest.raises(ValueError, match="no published peaks"):
+            RA.kernel_bound_s(1.0, 1.0, kind)
