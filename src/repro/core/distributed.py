@@ -477,11 +477,11 @@ def solve_distributed(
     # non-recording tracer by default; `solve_maxcut --trace-out`
     # installs a recording one)
     tr = trace_mod.get_tracer()
-    root = tr.begin("solve", n=graph.n, n_edges=graph.n_edges,
-                    mesh=dict(mesh.shape))
-    with tr.attach(root):
+    spans = {}  # span name → ended span, for `paraqaoa.stage_timings`
+    with tr.span("solve", n=graph.n, n_edges=graph.n_edges,
+                 mesh=dict(mesh.shape)) as spans["solve"]:
         # ---- stage 1: host-side partition at the lifted budget -----------
-        with tr.span("partition", n_qubits=budget) as sp_part:
+        with tr.span("partition", n_qubits=budget) as spans["partition"]:
             part = partition or partition_for_solver(graph, budget)
             # each vertex's linear term lands in exactly one subproblem
             # (first-coverage rule; shared vertices see h = 0 downstream)
@@ -495,7 +495,6 @@ def solve_distributed(
         small = [i for i, s in enumerate(part.sizes) if s <= device_cap]
         big = [i for i, s in enumerate(part.sizes) if s > device_cap]
         if big and not model_axis:
-            tr.end(root)
             raise ValueError(
                 f"subgraphs of {max(part.sizes)} qubits exceed the "
                 f"{device_cap}-qubit device cap and the mesh has no "
@@ -504,29 +503,32 @@ def solve_distributed(
 
         bit_indices = np.zeros((part.m, cfg.top_k), dtype=np.int64)
         with tr.span("solve_pool", m=part.m, n_small=len(small),
-                     n_big=len(big)) as sp_solve:
+                     n_big=len(big)) as spans["solve_pool"]:
+            with tr.span("pool_pack") as spans["pool_pack"]:
+                if small:
+                    edges, weights, masks = qaoa_mod.pad_subgraph_arrays(
+                        [part.subgraphs[i] for i in small], device_cap
+                    )
+                    linears = (
+                        qaoa_mod.pad_linear_arrays(
+                            [sub_lins[i] for i in small], device_cap
+                        )
+                        if has_lin else None
+                    )
             if small:
-                edges, weights, masks = qaoa_mod.pad_subgraph_arrays(
-                    [part.subgraphs[i] for i in small], device_cap
-                )
-                linears = (
-                    qaoa_mod.pad_linear_arrays(
-                        [sub_lins[i] for i in small], device_cap
-                    )
-                    if has_lin else None
-                )
-                if data_axes:
-                    res = solve_pool(edges, weights, masks, qcfg, mesh,
-                                     axes=data_axes, linears=linears)
-                elif has_lin:  # model-only mesh: single-device pool
-                    res = qaoa_mod.solve_subgraph_batch_program(
-                        qcfg, has_linear=True
-                    )(edges, weights, masks, linears)
-                else:
-                    res = qaoa_mod.solve_subgraph_batch_program(qcfg)(
-                        edges, weights, masks
-                    )
-                bit_indices[small] = np.asarray(res.bitstrings)
+                with tr.span("pool_run") as spans["pool_run"]:
+                    if data_axes:
+                        res = solve_pool(edges, weights, masks, qcfg, mesh,
+                                         axes=data_axes, linears=linears)
+                    elif has_lin:  # model-only mesh: single-device pool
+                        res = qaoa_mod.solve_subgraph_batch_program(
+                            qcfg, has_linear=True
+                        )(edges, weights, masks, linears)
+                    else:
+                        res = qaoa_mod.solve_subgraph_batch_program(qcfg)(
+                            edges, weights, masks
+                        )
+                    bit_indices[small] = np.asarray(res.bitstrings)
             # oversized subproblems: grouped by qubit count and run as
             # stacked batches through one cached sharded-engine program per
             # n (edge arrays padded with exact-no-op zero rows) — instead
@@ -586,44 +588,46 @@ def solve_distributed(
         #            `solve`;
         # "single":  keep the merge on one device (pool/statevector only).
         if merge_mode not in ("auto", "striped", "single"):
-            tr.end(root)
             raise ValueError(f"unknown merge_mode {merge_mode!r}")
-        with tr.span("merge", m=part.m) as sp_merge:
-            plan = merge_mod.build_merge_plan(
-                part, bit_indices, cfg.top_k,
-                linear=prob.linear if has_lin else None,
-            )
-            bw = cfg.beam_width or merge_mod.exact_beam_width(
-                cfg.top_k, part.m, cap=cfg.beam_cap
-            )
-            # merge_sharded stripes over one axis only (the innermost data
-            # axis); a `pod` axis replicates the striped sweep rather than
-            # widening it
-            n_shards = int(mesh.shape[data_axes[-1]]) if data_axes else 1
-            sl = min(cfg.merge_level if split_level is None else split_level,
-                     part.m - 1)
-            per_shard = None
-            if n_shards > 1 and part.m > 1 and merge_mode != "single":
-                w_exact = merge_mod.striped_beam_width(
-                    cfg.top_k, part.m, n_shards, sl, cap=cfg.beam_cap
+        with tr.span("merge", m=part.m) as spans["merge"]:
+            with tr.span("merge_plan") as spans["merge_plan"]:
+                plan, bw = para_mod.merge_inputs(
+                    part, bit_indices, cfg,
+                    linear=prob.linear if has_lin else None,
                 )
-                if w_exact is not None and (cfg.beam_width is None or bw >= 2 * cfg.top_k**part.m):
-                    per_shard = w_exact
-                elif merge_mode == "striped":
-                    per_shard = max(-(-bw // n_shards), 2 * cfg.top_k)
-            if per_shard is not None:
-                assign, val = merge_sharded(
-                    plan, per_shard, mesh, axis=data_axes[-1], split_level=sl
+                # merge_sharded stripes over one axis only (the innermost
+                # data axis); a `pod` axis replicates the striped sweep
+                # rather than widening it
+                n_shards = int(mesh.shape[data_axes[-1]]) if data_axes else 1
+                sl = min(
+                    cfg.merge_level if split_level is None else split_level,
+                    part.m - 1,
                 )
-                assignment = np.asarray(assign).reshape(-1)[: graph.n]
-                cut = float(np.asarray(val).reshape(-1)[0])
-            else:
-                merged = merge_mod.merge_scan(plan, bw)
-                assignment = np.asarray(merged.assignment)
-                cut = float(merged.cut_value)
+                per_shard = None
+                if n_shards > 1 and part.m > 1 and merge_mode != "single":
+                    w_exact = merge_mod.striped_beam_width(
+                        cfg.top_k, part.m, n_shards, sl, cap=cfg.beam_cap
+                    )
+                    if w_exact is not None and (cfg.beam_width is None or bw >= 2 * cfg.top_k**part.m):
+                        per_shard = w_exact
+                    elif merge_mode == "striped":
+                        per_shard = max(-(-bw // n_shards), 2 * cfg.top_k)
+            with tr.span("merge_scan", beam=bw,
+                         per_shard=per_shard) as spans["merge_scan"]:
+                if per_shard is not None:
+                    assign, val = merge_sharded(
+                        plan, per_shard, mesh, axis=data_axes[-1],
+                        split_level=sl,
+                    )
+                    assignment = np.asarray(assign).reshape(-1)[: graph.n]
+                    cut = float(np.asarray(val).reshape(-1)[0])
+                else:
+                    merged = merge_mod.merge_scan(plan, bw)
+                    assignment = np.asarray(merged.assignment)
+                    cut = float(merged.cut_value)
 
         # ---- optional beyond-paper refinement ----------------------------
-        with tr.span("refine", steps=cfg.refine_steps) as sp_refine:
+        with tr.span("refine", steps=cfg.refine_steps) as spans["refine"]:
             if cfg.refine_steps > 0:
                 from repro.core.baselines.local_search import refine
 
@@ -631,23 +635,17 @@ def solve_distributed(
                     part.graph, assignment, cfg.refine_steps,
                     linear=prob.linear if has_lin else None,
                 )
-    tr.end(root)
 
-    # re-score with the full objective; the merge's beam score must agree
-    # on the internal (offset-free) part
-    obj = float(problem_value(prob, jnp.asarray(assignment)))
+        # re-score with the full objective; the merge's beam score must
+        # agree on the internal (offset-free) part
+        with tr.span("rescore") as spans["rescore"]:
+            obj = float(problem_value(prob, jnp.asarray(assignment)))
     internal = obj - prob.offset
     if cfg.refine_steps == 0:
         assert abs(internal - cut) < 1e-2 * max(1.0, abs(internal)), (internal, cut)
     cut = obj
 
-    timings = {
-        "partition_s": sp_part.duration_s,
-        "solve_s": sp_solve.duration_s,
-        "merge_s": sp_merge.duration_s,
-        "refine_s": sp_refine.duration_s,
-        "total_s": root.duration_s,
-    }
+    timings, compiles = para_mod.stage_timings(spans)
     from repro.core.pei import SolveReport
 
     report = SolveReport(
@@ -675,4 +673,5 @@ def solve_distributed(
         partition=part,
         report=report,
         timings=timings,
+        compiles=compiles,
     )

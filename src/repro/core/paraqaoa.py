@@ -69,6 +69,36 @@ class ParaQAOAOutput:
     partition: Partition
     report: SolveReport
     timings: dict
+    # backend compiles (cache loads included) billed to each stage span
+    compiles: dict = dataclasses.field(default_factory=dict)
+
+
+# `timings` key → the span whose duration it reports. One table for
+# `solve` and `distributed.solve_distributed`, so their keys cannot drift.
+TIMING_SPANS = (
+    ("partition_s", "partition"),
+    ("solve_s", "solve_pool"),
+    ("pool_pack_s", "pool_pack"),
+    ("merge_s", "merge"),
+    ("merge_plan_s", "merge_plan"),
+    ("merge_scan_s", "merge_scan"),
+    ("refine_s", "refine"),
+    ("total_s", "solve"),
+)
+
+
+def stage_timings(spans: dict) -> tuple[dict, dict]:
+    """(timings, compiles) of one solve from its ended spans by name.
+
+    ``timings`` holds every `TIMING_SPANS` duration plus ``compile_s``,
+    the seconds of JAX compiling inside the solve (the root span's
+    union of compile phases; 0 on an injected clock). ``compiles``
+    counts backend compiles per span.
+    """
+    timings = {key: spans[name].duration_s for key, name in TIMING_SPANS}
+    timings["compile_s"] = spans["solve"].attrs.get("compile_s", 0.0)
+    compiles = {name: s.attrs.get("compiles", 0) for name, s in spans.items()}
+    return timings, compiles
 
 
 def merge_inputs(
@@ -90,7 +120,7 @@ def merge_inputs(
 
 def merge_candidates(
     part: Partition, bit_indices: np.ndarray, cfg: ParaQAOAConfig,
-    linear=None,
+    linear=None, spans: dict | None = None,
 ) -> tuple[np.ndarray, float, int]:
     """Stage-3 merge of solved candidates → (assignment, score, beam width).
 
@@ -99,10 +129,21 @@ def merge_candidates(
     plan/beam computation is what keeps service results bit-identical to
     solo `solve` runs on the same knobs. The returned score is the internal
     (offset-free) objective: quadratic cut + linear terms.
+
+    Two leaf spans split host from device work: ``merge_plan`` (the host
+    plan and beam width) and ``merge_scan`` (the scan through the host
+    copy of its answer). ``spans``, when given, receives both by name.
     """
-    plan, bw = merge_inputs(part, bit_indices, cfg, linear=linear)
-    merged = merge_mod.merge_scan(plan, bw)
-    return np.asarray(merged.assignment), float(merged.cut_value), bw
+    tr = trace_mod.get_tracer()
+    with tr.span("merge_plan") as sp_plan:
+        plan, bw = merge_inputs(part, bit_indices, cfg, linear=linear)
+    with tr.span("merge_scan", beam=bw) as sp_scan:
+        merged = merge_mod.merge_scan(plan, bw)
+        assignment = np.asarray(merged.assignment)
+        score = float(merged.cut_value)
+    if spans is not None:
+        spans.update(merge_plan=sp_plan, merge_scan=sp_scan)
+    return assignment, score, bw
 
 
 def solve(
@@ -127,39 +168,44 @@ def solve(
     # stamping as before; `solve_maxcut --trace-out` installs a
     # recording tracer and the same spans become the exported trace
     tr = trace_mod.get_tracer()
-    with tr.span("solve", n=graph.n, n_edges=graph.n_edges) as root:
+    spans = {}  # span name → ended span, for `stage_timings`
+    with tr.span("solve", n=graph.n, n_edges=graph.n_edges) as spans["solve"]:
         # ---- stage 1: graph partition (paper Alg. 1) ---------------------
-        with tr.span("partition", n_qubits=cfg.n_qubits) as sp_part:
+        with tr.span("partition", n_qubits=cfg.n_qubits) as spans["partition"]:
             part = partition or partition_for_solver(graph, cfg.n_qubits)
             sub_lins = split_linear(part, prob.linear) if has_lin else None
 
         # ---- stage 2: parallelized QAOA execution ------------------------
         with tr.span("solve_pool", m=part.m,
-                     n_qubits=cfg.n_qubits) as sp_solve:
+                     n_qubits=cfg.n_qubits) as spans["solve_pool"]:
             qcfg = cfg.qaoa_config()
-            edges, weights, masks = qaoa_mod.pad_subgraph_arrays(
-                part.subgraphs, qcfg.n_qubits
-            )
-            if has_lin:
-                linears = qaoa_mod.pad_linear_arrays(sub_lins, qcfg.n_qubits)
-                result = qaoa_mod.solve_subgraph_batch_program(
-                    qcfg, has_linear=True
-                )(edges, weights, masks, linears)
-            else:
-                result = qaoa_mod.solve_subgraph_batch_program(qcfg)(
-                    edges, weights, masks
+            with tr.span("pool_pack") as spans["pool_pack"]:
+                edges, weights, masks = qaoa_mod.pad_subgraph_arrays(
+                    part.subgraphs, qcfg.n_qubits
                 )
-            bit_indices = np.asarray(result.bitstrings)  # (M, K)
+                if has_lin:
+                    linears = qaoa_mod.pad_linear_arrays(sub_lins,
+                                                         qcfg.n_qubits)
+            with tr.span("pool_run") as spans["pool_run"]:
+                if has_lin:
+                    result = qaoa_mod.solve_subgraph_batch_program(
+                        qcfg, has_linear=True
+                    )(edges, weights, masks, linears)
+                else:
+                    result = qaoa_mod.solve_subgraph_batch_program(qcfg)(
+                        edges, weights, masks
+                    )
+                bit_indices = np.asarray(result.bitstrings)  # (M, K)
 
         # ---- stage 3: level-aware parallel merge -------------------------
-        with tr.span("merge", m=part.m) as sp_merge:
+        with tr.span("merge", m=part.m) as spans["merge"]:
             assignment, cut, bw = merge_candidates(
                 part, bit_indices, cfg,
-                linear=prob.linear if has_lin else None,
+                linear=prob.linear if has_lin else None, spans=spans,
             )
 
         # ---- optional beyond-paper refinement ----------------------------
-        with tr.span("refine", steps=cfg.refine_steps) as sp_refine:
+        with tr.span("refine", steps=cfg.refine_steps) as spans["refine"]:
             if cfg.refine_steps > 0:
                 from repro.core.baselines.local_search import refine
 
@@ -168,21 +214,17 @@ def solve(
                     linear=prob.linear if has_lin else None,
                 )
 
-    # sanity: merge's incremental score must equal a from-scratch evaluation
-    # of the internal (offset-free) objective; report the full objective
-    obj = float(problem_value(prob, jnp.asarray(assignment)))
+        # sanity: merge's incremental score must equal a from-scratch
+        # evaluation of the internal (offset-free) objective; report the
+        # full objective
+        with tr.span("rescore") as spans["rescore"]:
+            obj = float(problem_value(prob, jnp.asarray(assignment)))
     internal = obj - prob.offset
     if cfg.refine_steps == 0:
         assert abs(internal - cut) < 1e-2 * max(1.0, abs(internal)), (internal, cut)
     cut = obj
 
-    timings = {
-        "partition_s": sp_part.duration_s,
-        "solve_s": sp_solve.duration_s,
-        "merge_s": sp_merge.duration_s,
-        "refine_s": sp_refine.duration_s,
-        "total_s": root.duration_s,
-    }
+    timings, compiles = stage_timings(spans)
     report = SolveReport(
         method="paraqaoa",
         n_vertices=graph.n,
@@ -196,4 +238,5 @@ def solve(
         partition=part,
         report=report,
         timings=timings,
+        compiles=compiles,
     )

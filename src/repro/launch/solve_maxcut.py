@@ -91,6 +91,11 @@ def build_parser() -> argparse.ArgumentParser:
                     default="jsonl",
                     help="trace export format: 'jsonl' (one span per "
                     "line) or 'chrome' (Perfetto-loadable trace events)")
+    ap.add_argument("--profile-dir", type=str, default=None, metavar="DIR",
+                    help="take a jax.profiler trace of the solve into DIR "
+                    "with a recording tracer: the pipeline's stage spans "
+                    "land on the profile's host plane, on one timeline "
+                    "with the device's operations")
     return ap
 
 
@@ -158,11 +163,19 @@ def run(argv=None):
         refine_steps=args.refine,
         sharded_opt_steps=args.sharded_opt_steps,
     )
-    # §8: tracing is enabled only when an export path is requested; the
-    # pipeline's ambient-tracer spans become the exported trace
-    tracer = Tracer(record=True) if args.trace_out else None
+    # §8: tracing is enabled only when an export path or a profile is
+    # requested; the pipeline's ambient-tracer spans become the exported
+    # trace and, under the profiler, annotations on its host plane
+    tracer = (Tracer(record=True) if args.trace_out or args.profile_dir
+              else None)
     scope = use_tracer(tracer) if tracer else contextlib.nullcontext()
-    with scope:
+    if args.profile_dir:
+        import jax
+
+        profile = jax.profiler.trace(args.profile_dir)
+    else:
+        profile = contextlib.nullcontext()
+    with profile, scope:
         if mesh_spec is not None:
             out = solve_distributed(
                 instance, cfg, mesh_spec,
@@ -176,7 +189,9 @@ def run(argv=None):
                   f"(sharded_opt_steps={extra['sharded_opt_steps']})")
         else:
             out = solve(instance, cfg)
-    if tracer is not None:
+    if args.profile_dir:
+        print(f"[maxcut] profile: {args.profile_dir}")
+    if args.trace_out:
         tracer.export(args.trace_out, args.trace_format)
         print(f"[maxcut] trace ({args.trace_format}, "
               f"{len(tracer.spans)} spans): {args.trace_out}")

@@ -10,14 +10,20 @@ wall clock except `repro.obs.clock`:
   - `metrics` — `MetricsRegistry`, `Counter` / `Gauge` / `Histogram`,
     exact nearest-rank `percentile`; JSON + Prometheus exposition.
   - `ledger`  — `CompileLedger`: every cached-program build, per-shape
-    compile, and trace-time kernel dispatch.
+    compile, and trace-time kernel dispatch; its listener bills JAX's
+    own compile events to the ambient tracer's open spans.
 
 `validate` holds the trace/metrics schema validators the CI obs job
 runs (``python -m repro.obs.validate``).
 """
 
 from repro.obs.clock import default_clock
-from repro.obs.ledger import CompileLedger, LedgerEvent, get_ledger
+from repro.obs.ledger import (
+    CompileLedger,
+    LedgerEvent,
+    get_ledger,
+    listen_to_jax,
+)
 from repro.obs.metrics import (
     DEFAULT_BUCKETS,
     Counter,
@@ -27,6 +33,9 @@ from repro.obs.metrics import (
     percentile,
 )
 from repro.obs.trace import Span, Tracer, get_tracer, set_tracer, use_tracer
+
+# every JAX compile billed to the span that caused it (ledger docstring)
+listen_to_jax()
 
 __all__ = [
     "DEFAULT_BUCKETS",

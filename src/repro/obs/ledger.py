@@ -20,6 +20,14 @@ quantity:
     storms (e.g. `merge_scan` retracing per novel graph shape) show up
     as op-event counts with the implementation that was active.
 
+Beside these, the ledger listens to JAX's own compile events
+(``/jax/core/compile/{jaxpr_trace,jaxpr_to_mlir_module,backend_compile}
+_duration``, one listener registered at import of `repro.obs`), which
+see every compile, not only the cached programs', and bills each to the
+ambient tracer's open spans (`Tracer.bill_compile`): the span that
+caused a compile gets a ``compile`` child, and each open span its
+``compile_s`` / ``compiles`` attributes.
+
 A warm system is therefore *provably* warm: re-running a workload after
 `reset()` with all caches intact records zero build and zero compile
 events (the acceptance gate in tests/test_obs.py and
@@ -34,6 +42,14 @@ program caches it mirrors are process-global too.
 from __future__ import annotations
 
 import dataclasses
+
+from repro.obs import trace as trace_mod
+
+# JAX's compile-path duration events, by the phase name billed to spans
+JAX_COMPILE_EVENTS = {
+    f"/jax/core/compile/{phase}_duration": phase
+    for phase in ("jaxpr_trace", "jaxpr_to_mlir_module", "backend_compile")
+}
 
 # op events dedup per (op, impl) with counts, but build/compile events
 # are kept verbatim; a runaway shape storm stops recording (and starts
@@ -83,6 +99,15 @@ class CompileLedger:
         k = (op, impl)
         self.op_traces[k] = self.op_traces.get(k, 0) + 1
 
+    def on_jax_event(self, event: str, duration_secs: float,
+                     **kwargs) -> None:
+        """`jax.monitoring` duration listener: bill a compile phase to
+        the span open in the ambient tracer when it ended."""
+        phase = JAX_COMPILE_EVENTS.get(event)
+        if phase is not None:
+            trace_mod.get_tracer().bill_compile(
+                phase, str(kwargs.get("fun_name", "")), float(duration_secs))
+
     # --------------------------------------------------------------- reading --
     def count(self, kind: str) -> int:
         return sum(1 for e in self.events if e.kind == kind)
@@ -127,3 +152,14 @@ _LEDGER = CompileLedger()
 
 def get_ledger() -> CompileLedger:
     return _LEDGER
+
+
+def listen_to_jax() -> None:
+    """Register the global ledger's listener for JAX's compile events;
+    called once, at import of `repro.obs`. Without JAX (the stdlib-only
+    validator's setting) there is nothing to listen to."""
+    try:
+        from jax import monitoring
+    except ImportError:
+        return
+    monitoring.register_event_duration_secs_listener(_LEDGER.on_jax_event)

@@ -25,6 +25,12 @@ exist unconditionally — but nothing is retained or exported, which is
 what keeps tracing-off overhead at zero allocation growth. `--trace-out`
 on the launch drivers constructs the tracer with ``record=True``.
 
+A tracer on the real clock (`default_clock`) shares its timeline with
+JAX: a recording one enters a `jax.profiler.TraceAnnotation` for every
+stack-scoped span, so a profiler trace shows the program's stages around
+the device's operations, and every JAX compile phase is billed to the
+open spans (`bill_compile`, fed by the compile ledger's listener).
+
 Retained spans export as JSON-lines (one span object per line, sorted
 by ``(t0, span_id)`` so identical runs produce byte-identical files)
 and as Chrome trace-event format (``ph: "X"`` complete events,
@@ -95,7 +101,8 @@ class Tracer:
         self.spans: list[Span] = []  # ended spans, when recording
         self._stack: list[Span] = []  # implicit-parent stack
         self._next_id = 1
-        self._open = 0  # begun-but-unended spans (export sanity)
+        # disjoint, sorted compile intervals still inside an open span
+        self._compile_union: list[tuple[float, float]] = []
 
     # ------------------------------------------------------------ lifecycle --
     def begin(self, name: str, parent: Span | None = None, **attrs) -> Span:
@@ -114,7 +121,6 @@ class Tracer:
             attrs,
         )
         self._next_id += 1
-        self._open += 1
         return span
 
     def end(self, span: Span, **attrs) -> Span:
@@ -124,7 +130,6 @@ class Tracer:
         if attrs:
             span.attrs.update(attrs)
         span.t1 = self._clock()
-        self._open -= 1
         if self.record:
             self.spans.append(span)
         return span
@@ -157,17 +162,83 @@ class Tracer:
             self.spans.append(span)
         return span
 
+    @property
+    def on_real_clock(self) -> bool:
+        """True when stamps come from `default_clock`, the clock JAX's
+        compile events and the profiler's host plane share."""
+        return self._clock is default_clock
+
     @contextlib.contextmanager
     def span(self, name: str, parent: Span | None = None, **attrs):
         """Stack-scoped span: children begun inside the block nest
-        under it implicitly."""
+        under it implicitly. A recording tracer on the real clock also
+        opens a `jax.profiler.TraceAnnotation` of the span's name, so a
+        profiler trace taken around the block shows the span on its host
+        plane."""
         s = self.begin(name, parent=parent, **attrs)
         self._stack.append(s)
         try:
-            yield s
+            if self.record and self.on_real_clock:
+                import jax.profiler
+
+                with jax.profiler.TraceAnnotation(name):
+                    yield s
+            else:
+                yield s
         finally:
             self._stack.pop()
             self.end(s)
+
+    def bill_compile(self, phase: str, fun_name: str,
+                     duration_s: float) -> None:
+        """Bill one finished JAX compile phase to the open spans.
+
+        JAX reports a phase when it ends, with its duration, so the
+        phase is the interval ``[now - duration_s, now]``. The innermost
+        open span gets a retroactive ``compile`` child (when recording);
+        every open span adds the phase's seconds to its ``compile_s``
+        attribute and, for a backend compile (a cache load included), one
+        to ``compiles``. Phases nest (a trace inside a lowering), so
+        ``compile_s`` counts the union of the intervals, each second once.
+        Only a tracer on the real clock is billed: an injected clock's
+        stamps share no timeline with JAX's.
+        """
+        if not self._stack or not self.on_real_clock:
+            return
+        t1 = self._clock()
+        t0 = t1 - duration_s
+        pieces = self._union_add(t0, t1)
+        for s in self._stack:
+            gained = sum(max(0.0, b - max(a, s.t0)) for a, b in pieces)
+            s.attrs["compile_s"] = s.attrs.get("compile_s", 0.0) + gained
+            if phase == "backend_compile":
+                s.attrs["compiles"] = s.attrs.get("compiles", 0) + 1
+        if self.record:
+            inner = self._stack[-1]
+            self.span_at("compile", max(t0, inner.t0), t1, parent=inner,
+                         fun_name=fun_name, phase=phase)
+
+    def _union_add(self, t0: float, t1: float) -> list[tuple[float, float]]:
+        """Merge ``[t0, t1]`` into the compile-interval union; return the
+        pieces of it the union did not already cover."""
+        floor = self._stack[0].t0  # nothing before the oldest open span
+        pieces, cur, lo, hi = [], t0, t0, t1
+        kept = []
+        for a, b in self._compile_union:
+            if b < floor:
+                continue
+            if b < t0 or a > t1:
+                kept.append((a, b))
+                continue
+            if a > cur:
+                pieces.append((cur, a))
+            cur = max(cur, b)
+            lo, hi = min(lo, a), max(hi, b)
+        if cur < t1:
+            pieces.append((cur, t1))
+        kept.append((lo, hi))
+        self._compile_union = sorted(kept)
+        return pieces
 
     @contextlib.contextmanager
     def attach(self, span: Span):
