@@ -6,15 +6,22 @@ Used two ways:
     (`refine`) — a few sweeps of best-improvement flips recover most of the
     AR lost to dropped inter-partition edges at negligible cost.
 
-The flip gain for vertex v is  g(v) = deg_w(v) - 2 * cut_incident(v),
-computed for all vertices at once from the edge list (no dense matrix), so
-one sweep is O(|E|) and fully vectorized.
+The flip gain for vertex v is  g(v) = deg_w(v) - 2 * cut_incident(v)
+(plus the linear term's h_v * (1 - 2 s_v)). The host computes it for all
+vertices once, in one pass over the edge list, and sorts both directions
+of every edge by source vertex (a CSR adjacency). On the device, flipping
+v then negates g(v) and moves each neighbour u's gain by 2w(u, v): down
+if u was on v's side, up if not. So a step is an argmax over the vertices
+plus O(max degree) work, not O(|E|). A step reads a neighbour window of
+static length, the largest degree rounded up to a power-of-two bucket, so
+the device program's shapes are (n, E_pad, bucket).
 """
 
 from __future__ import annotations
 
 import functools
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -22,42 +29,108 @@ import numpy as np
 
 from repro.core.graph import Graph, cut_value
 from repro.core.pei import SolveReport
+from repro.obs import trace as trace_mod
+
+# smallest neighbour window a step reads: graphs whose largest degree
+# differs below it share one program
+MIN_DEGREE_BUCKET = 128
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5))
-def _sweeps(edges, weights, linear, assignment, steps: int, n: int):
+class Adjacency(NamedTuple):
+    """Both directions of every edge sorted by source vertex, on the
+    device: the neighbours of v are ``dst[start[v]:start[v + 1]]``.
+    Self-loops, the graph's zero-weight (0, 0) padding rows among them,
+    sort past the last vertex and fall out of every row: an edge (v, v)
+    is never cut and moves no gain."""
+
+    dst: jnp.ndarray  # (2 E_pad,) int32
+    w: jnp.ndarray  # (2 E_pad,) float32
+    start: jnp.ndarray  # (n + 1,) int32
+    bucket: int  # the largest degree rounded up to a power of two
+
+
+def _adjacency(graph: Graph) -> Adjacency:
+    """Built on the host. At 1.28 M edges the host's stable radix sort of
+    16-bit keys, its gathers and the gains took 0.06 s, where the same
+    gathers and scatter took 0.13 s on a TPU v5e; a device sort compiles
+    for tens of seconds at every new edge count."""
+    n = graph.n
+    e = np.asarray(graph.edges)
+    w = np.asarray(graph.weights)
+    src = np.concatenate([e[:, 0], e[:, 1]])
+    dst = np.concatenate([e[:, 1], e[:, 0]])
+    key = np.where(src == dst, n, src).astype(np.min_scalar_type(n))
+    order = np.argsort(key, kind="stable")
+    degree = np.bincount(key, minlength=n + 1)[:n]
+    start = np.zeros((n + 1,), np.int32)
+    np.cumsum(degree, out=start[1:])
+    largest = int(degree.max(initial=0))
+    bucket = max(MIN_DEGREE_BUCKET, 1 << max(0, largest - 1).bit_length())
+    return Adjacency(jnp.asarray(dst[order]),
+                     jnp.asarray(np.concatenate([w, w])[order]),
+                     jnp.asarray(start), bucket)
+
+
+def _gains(graph: Graph, s: np.ndarray, linear) -> np.ndarray:
+    """(n,) float32 gain of flipping each vertex alone, summed in float64:
+    its uncut weight minus its cut weight, plus h_v * (1 - 2 s_v)."""
+    e = np.asarray(graph.edges)
+    w = np.asarray(graph.weights, np.float64)
+    u, v = e[:, 0], e[:, 1]
+    signed = np.where(u == v, 0.0, np.where(s[u] == s[v], w, -w))
+    gain = (np.bincount(u, signed, graph.n)
+            + np.bincount(v, signed, graph.n))
+    if linear is not None:
+        gain += np.asarray(linear, np.float64) * (1.0 - 2.0 * s)
+    return gain.astype(np.float32)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7))
+def _sweeps(dst, w, start, gain, assignment, eps, steps: int, bucket: int):
+    """Up to ``steps`` best-improvement flips from ``gain``; (assignment,
+    flips). The loop stops at the first step with no improving flip: the
+    state is a fixed point from there on."""
+    # padded so that a row's window never runs off the end
+    dst = jnp.concatenate([dst, jnp.zeros((bucket,), dst.dtype)])
+    w = jnp.concatenate([w, jnp.zeros((bucket,), w.dtype)])
+    lane = jnp.arange(bucket, dtype=start.dtype)
+
+    def best(gain):
+        v = jnp.argmax(gain)
+        return v, gain[v] > eps
+
+    def flip(carry):
+        i, s, gain, v, _ = carry
+        lo = start[v]
+        u = jax.lax.dynamic_slice(dst, (lo,), (bucket,))
+        wu = jax.lax.dynamic_slice(w, (lo,), (bucket,))
+        # edge (v, u) flips between cut and uncut: u's gain moves by 2w
+        delta = jnp.where(s[u] == s[v], -2.0, 2.0) * wu
+        gain = gain.at[u].add(jnp.where(lane < start[v + 1] - lo, delta, 0.0))
+        gain = gain.at[v].set(-gain[v])
+        s = s.at[v].set(1 - s[v])
+        return (i + 1, s, gain, *best(gain))
+
+    flips, s, *_ = jax.lax.while_loop(
+        lambda c: (c[0] < steps) & c[4], flip,
+        (0, assignment, gain, *best(gain)))
+    return s, flips
+
+
+def _flips(graph: Graph, adj: Adjacency, s: np.ndarray, steps: int,
+           linear=None) -> tuple[np.ndarray, int]:
+    """(assignment (n,) int8, accepted flips) after ``steps`` flips."""
+    s = np.asarray(s, np.int32)
     # Acceptance threshold is *relative* to the objective scale: the old
     # absolute 1e-6 silently rejected every real improvement on graphs with
     # uniformly tiny weights (and accepted float noise on huge ones).
-    scale = jnp.sum(jnp.abs(weights)) + jnp.sum(jnp.abs(linear))
-    eps = 1e-6 * scale
-
-    def gains(s):
-        su = s[edges[:, 0]]
-        sv = s[edges[:, 1]]
-        crossed = (su ^ sv).astype(weights.dtype)
-        # incident cut weight and degree per vertex
-        inc = jnp.zeros((n,), weights.dtype)
-        inc = inc.at[edges[:, 0]].add(weights * crossed)
-        inc = inc.at[edges[:, 1]].add(weights * crossed)
-        deg = jnp.zeros((n,), weights.dtype)
-        deg = deg.at[edges[:, 0]].add(weights)
-        deg = deg.at[edges[:, 1]].add(weights)
-        quad = deg - 2.0 * inc  # gain of flipping each vertex alone
-        # flipping v changes the linear term by h_v * (1 - 2 s_v)
-        return quad + linear * (1.0 - 2.0 * s.astype(weights.dtype))
-
-    def body(s, _):
-        g = gains(s)
-        v = jnp.argmax(g)
-        improve = g[v] > eps
-        s = jnp.where(
-            jnp.arange(n) == v, jnp.where(improve, 1 - s[v], s[v]), s
-        ).astype(s.dtype)
-        return s, None
-
-    s, _ = jax.lax.scan(body, assignment, None, length=steps)
-    return s
+    scale = np.abs(np.asarray(graph.weights, np.float64)).sum()
+    if linear is not None:
+        scale += np.abs(np.asarray(linear, np.float64)).sum()
+    out, flips = jax.device_get(_sweeps(
+        adj.dst, adj.w, adj.start, _gains(graph, s, linear), s,
+        np.float32(1e-6 * scale), steps, adj.bucket))
+    return np.asarray(out, np.int8), int(flips)
 
 
 def _score(graph: Graph, s: np.ndarray, linear) -> float:
@@ -76,16 +149,13 @@ def refine(graph: Graph, assignment: np.ndarray, steps: int, linear=None):
     """Best-improvement 1-flip refinement of an existing assignment.
 
     ``linear`` (n,) f32, optional, refines the full internal objective
-    (quadratic cut + per-vertex linear terms) for QUBO/MIS problems.
+    (quadratic cut + per-vertex linear terms) for QUBO/MIS problems. The
+    accepted flips and the degree bucket go on the innermost open span
+    (``flips``, ``degree_bucket``).
     """
-    s = jnp.asarray(assignment, dtype=jnp.int32)
-    lin = (
-        jnp.zeros((graph.n,), dtype=jnp.float32)
-        if linear is None
-        else jnp.asarray(linear, dtype=jnp.float32)
-    )
-    s = _sweeps(graph.edges, graph.weights, lin, s, steps, graph.n)
-    out = np.asarray(s, dtype=np.int8)
+    adj = _adjacency(graph)
+    out, flips = _flips(graph, adj, assignment, steps, linear)
+    trace_mod.get_tracer().annotate(flips=flips, degree_bucket=adj.bucket)
     return out, _score(graph, out, linear)
 
 
@@ -93,12 +163,11 @@ def local_search(graph: Graph, restarts: int = 8, steps: int = 200, seed: int = 
     """Random-restart 1-flip local search baseline."""
     t0 = time.perf_counter()
     rng = np.random.default_rng(seed)
-    zeros = jnp.zeros((graph.n,), dtype=jnp.float32)
+    adj = _adjacency(graph)
     best_s, best_v = None, -np.inf
     for _ in range(restarts):
         s0 = rng.integers(0, 2, size=graph.n).astype(np.int32)
-        s = _sweeps(graph.edges, graph.weights, zeros, jnp.asarray(s0), steps, graph.n)
-        s = np.asarray(s, dtype=np.int8)
+        s, _ = _flips(graph, adj, s0, steps)
         v = _score(graph, s, None)
         if v > best_v:
             best_v, best_s = v, s

@@ -240,6 +240,13 @@ class Tracer:
         self._compile_union = sorted(kept)
         return pieces
 
+    def annotate(self, **attrs) -> None:
+        """Merge attributes into the innermost open stack-scoped span, so
+        library code reports what it did (e.g. refinement's accepted
+        flips) without a span parameter; outside any span, nothing."""
+        if self._stack:
+            self._stack[-1].attrs.update(attrs)
+
     @contextlib.contextmanager
     def attach(self, span: Span):
         """Push an *existing* (still-open) span onto the implicit stack

@@ -10,6 +10,7 @@ QUBOs, service≡solo bit-parity for weighted and QUBO traffic, and the
 local-search re-score/epsilon bugfixes."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +34,7 @@ from repro.core.graph import (
 from repro.core.partition import connectivity_preserving_partition, split_linear
 from repro.kernels import ops
 from repro.kernels import ref
+from repro.obs.trace import Tracer, use_tracer
 from repro.service import SLA, ServiceConfig, SolveService
 from repro.service.canonical import canonical_key
 from repro.service.workload import problem_mix, relabel_problem
@@ -341,3 +343,114 @@ def test_refine_improves_qubo_objective():
     v0 = float(problem_value(prob, jnp.asarray(a0)))
     _, v = refine(prob.graph, a0, steps=80, linear=prob.linear)
     assert v >= v0 - 1e-6
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _sweeps_from_scratch(edges, weights, linear, assignment, steps, n):
+    """The refinement as it was before the incremental gains: every step
+    recomputes every vertex's gain from the whole edge list."""
+    eps = 1e-6 * (jnp.sum(jnp.abs(weights)) + jnp.sum(jnp.abs(linear)))
+
+    def gains(s):
+        crossed = (s[edges[:, 0]] ^ s[edges[:, 1]]).astype(weights.dtype)
+        inc = jnp.zeros((n,), weights.dtype)
+        inc = inc.at[edges[:, 0]].add(weights * crossed)
+        inc = inc.at[edges[:, 1]].add(weights * crossed)
+        deg = jnp.zeros((n,), weights.dtype)
+        deg = deg.at[edges[:, 0]].add(weights)
+        deg = deg.at[edges[:, 1]].add(weights)
+        return deg - 2.0 * inc + linear * (1.0 - 2.0 * s.astype(weights.dtype))
+
+    def body(s, _):
+        g = gains(s)
+        v = jnp.argmax(g)
+        improve = g[v] > eps
+        s = jnp.where(jnp.arange(n) == v,
+                      jnp.where(improve, 1 - s[v], s[v]), s).astype(s.dtype)
+        return s, None
+
+    return jax.lax.scan(body, assignment, None, length=steps)[0]
+
+
+def _star(n):
+    return Graph.from_edges(n, [(0, i) for i in range(1, n)])
+
+
+def _int_weighted(n, p, seed):
+    g = Graph.erdos_renyi(n, p, seed=seed)
+    w = np.random.default_rng(seed).integers(1, 10, size=g.n_edges)
+    return Graph.from_edges(n, np.asarray(g.edges), w.astype(np.float32))
+
+
+def _isolated(n, seed):
+    """Edges among the first half of the vertices only."""
+    g = Graph.erdos_renyi(n // 2, 0.2, seed=seed)
+    return Graph.from_edges(n, np.asarray(g.edges), pad_to=g.n_edges + 7)
+
+
+def _mis(n, p, seed):
+    prob = Problem.mis(Graph.erdos_renyi(n, p, seed=seed), penalty=2.0)
+    return prob.graph, prob.linear
+
+
+REFINE_CASES = {
+    # name: (graph, or (graph, linear); steps; expected degree bucket)
+    "gnp": (lambda: Graph.erdos_renyi(150, 0.1, seed=30), 300, 128),
+    "gnp-padded": (lambda: Graph.erdos_renyi(150, 0.1, seed=30, pad_to=2000),
+                   300, 128),
+    "gnp-partway": (lambda: Graph.erdos_renyi(150, 0.1, seed=31), 17, 128),
+    "int-weighted": (lambda: _int_weighted(120, 0.15, 32), 300, 128),
+    "spin-glass": (lambda: Graph.spin_glass(150, 0.1, seed=33), 300, 128),
+    "mis-linear": (lambda: _mis(60, 0.15, 34), 200, 128),
+    "star": (lambda: _star(300), 50, 512),
+    "isolated": (lambda: _isolated(90, 35), 200, 128),
+    "steps-0": (lambda: Graph.erdos_renyi(150, 0.1, seed=36), 0, 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REFINE_CASES))
+def test_refine_matches_from_scratch_sweeps(case):
+    """Incremental gains over the CSR adjacency make the same flips as the
+    from-scratch sweep: with integer weights and linear terms every gain is
+    an exact float32 integer, so assignment and value agree bit for bit."""
+    make, steps, bucket = REFINE_CASES[case]
+    made = make()
+    g, linear = made if isinstance(made, tuple) else (made, None)
+    lin = jnp.zeros((g.n,), jnp.float32) if linear is None else linear
+    a0 = np.random.default_rng(37).integers(0, 2, size=g.n).astype(np.int8)
+    tr = Tracer(record=True)
+    with use_tracer(tr), tr.span("refine") as span:
+        a, v = refine(g, a0, steps, linear=linear)
+    want = np.asarray(_sweeps_from_scratch(
+        g.edges, g.weights, lin, jnp.asarray(a0, jnp.int32), steps, g.n))
+    np.testing.assert_array_equal(a, want.astype(np.int8))
+    v_want = float(cut_value(g, jnp.asarray(want)))
+    if linear is not None:
+        v_want += float(np.asarray(linear, np.float64) @ want)
+    assert v == v_want
+    assert span.attrs["degree_bucket"] == bucket
+    assert 0 <= span.attrs["flips"] <= steps
+    if steps == 0:
+        np.testing.assert_array_equal(a, a0)
+
+
+def test_refine_bucket_ignores_padding_and_reuses_its_program():
+    """The (0, 0) zero-weight padding rows enter no neighbour list, so they
+    do not raise the degree bucket; a second graph of the same (n, E_pad,
+    bucket) runs the programs the first one compiled."""
+    spans = []
+    for seed in (38, 39):
+        g = Graph.erdos_renyi(211, 0.05, seed=seed, pad_to=4001)
+        assert 4001 - g.n_edges > 2 * 128  # vertex 0 would hold them all
+        tr = Tracer(record=True)
+        with use_tracer(tr), tr.span("refine") as span:
+            refine(g, np.zeros(g.n, np.int8), 40)
+        compiled = {s.attrs["fun_name"] for s in tr.spans
+                    if s.name == "compile"
+                    and s.attrs["phase"] == "backend_compile"}
+        spans.append((span, compiled))
+    (first, first_compiled), (second, second_compiled) = spans
+    assert first.attrs["degree_bucket"] == second.attrs["degree_bucket"] == 128
+    assert "jit(_sweeps)" in first_compiled
+    assert second_compiled == set()
+    assert second.attrs.get("compiles", 0) == 0

@@ -12,6 +12,7 @@ Sizes are the one-chip deployment of the 16,000-vertex instance:
 30 Adam steps (`ParaQAOAConfig` defaults).
 """
 
+import importlib
 import os
 
 import jax
@@ -158,3 +159,16 @@ def test_solve_pool_compiles_on_four_chip_mesh(topo):
     compiled = program.lower(*_solver_structs(m_pad, data)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert "all-gather" not in compiled.as_text()  # each chip keeps its rows
+
+
+def test_refine_sweeps_compile_for_v5e(one_chip):
+    """The refinement program of the 16,000-vertex instance: G(16000, 0.01)
+    has 1,279,920 edges and a largest degree near 220 (bucket 256)."""
+    ls = importlib.import_module("repro.core.baselines.local_search")
+    n, e = 16000, 1279920
+    compiled = ls._sweeps.lower(
+        _i32((2 * e,), one_chip), _f32((2 * e,), one_chip),
+        _i32((n + 1,), one_chip), _f32((n,), one_chip), _i32((n,), one_chip),
+        _f32((), one_chip), 200, 256).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2**30, mem
