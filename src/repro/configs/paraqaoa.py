@@ -5,7 +5,9 @@ from repro.core.paraqaoa import ParaQAOAConfig
 # production setting: 26-qubit solvers (the paper's GPU cap), pod-scale pool
 CONFIG = ParaQAOAConfig(
     n_qubits=26,
-    n_solvers=256,  # one per chip on a 16x16 pod
+    # one per chip on a 16x16 pod: `solve` runs the pool over a `data`
+    # mesh of the first 256 devices, so this config needs 256 of them
+    n_solvers=256,
     top_k=2,
     merge_level=2,
     p_layers=3,

@@ -49,6 +49,88 @@ def check_solve_pool():
     }
 
 
+def check_n_solvers():
+    """`solve` with ``n_solvers`` 4 (the pool over a `data` mesh of the
+    first 4 devices) against ``n_solvers`` 1: bit-identical pool
+    candidates, assignment and value, for Max-Cut and a QUBO with linear
+    terms, at M a multiple of 4 (43 vertices, M = 8) and not (40, M = 7:
+    one padding row). Too many solvers for the devices raise; `solve_pool`
+    pads a batch to its mesh and matches the one-device program."""
+    import dataclasses
+
+    from repro.core import paraqaoa as para_mod
+    from repro.core.graph import Problem
+
+    def solve_recorded(prob, cfg):
+        """(output, pool bitstrings) of one solve: the pool's answer is
+        kept where `solve` asks for its program."""
+        seen = []
+        make = qaoa_mod.solve_subgraph_batch_program
+
+        def recorded(qcfg, has_linear=False):
+            program = make(qcfg, has_linear)
+
+            def run(*arrays):
+                res = program(*arrays)
+                seen.append(np.asarray(res.bitstrings))
+                return res
+
+            return run
+
+        qaoa_mod.solve_subgraph_batch_program = recorded
+        try:
+            return para_mod.solve(prob, cfg), seen[0]
+        finally:
+            qaoa_mod.solve_subgraph_batch_program = make
+
+    cfg = para_mod.ParaQAOAConfig(n_qubits=7, top_k=2, p_layers=2,
+                                  opt_steps=6)
+    out = {}
+    for name, n in (("even", 43), ("pad", 40)):
+        g = Graph.erdos_renyi(n, 0.3, seed=n)
+        rng = np.random.default_rng(n)
+        e = np.asarray(g.edges)
+        problems = {
+            "maxcut": g,
+            "qubo": Problem.qubo(
+                n, e, rng.normal(size=e.shape[0]).astype(np.float32),
+                linear=rng.normal(size=n).astype(np.float32), offset=0.5),
+        }
+        for kind, prob in problems.items():
+            key = f"{kind}_{name}"
+            one, bits_one = solve_recorded(prob, cfg)
+            four, bits_four = solve_recorded(
+                prob, dataclasses.replace(cfg, n_solvers=4))
+            out[key + "_m"] = int(one.partition.m)
+            out[key + "_bits_equal"] = bool(
+                np.array_equal(bits_one, bits_four))
+            out[key + "_assignment_equal"] = bool(
+                np.array_equal(one.assignment, four.assignment))
+            out[key + "_value_equal"] = bool(
+                one.cut_value == four.cut_value)
+
+    try:
+        para_mod.solve(Graph.erdos_renyi(20, 0.3, seed=1),
+                       dataclasses.replace(cfg, n_solvers=16))
+        out["too_many_solvers_error"] = ""
+    except ValueError as err:
+        out["too_many_solvers_error"] = str(err)
+
+    g = Graph.erdos_renyi(40, 0.3, seed=5)
+    part = connectivity_preserving_partition(g, 7)
+    qcfg = cfg.qaoa_config()
+    arrays = qaoa_mod.pad_subgraph_arrays(part.subgraphs, 7)
+    want = qaoa_mod.solve_subgraph_batch_program(qcfg)(*arrays)
+    got = dist.solve_pool(*arrays, qcfg, compat.make_mesh((4,), ("data",),
+                          devices=jax.devices()[:4]))
+    out["pool_m"] = int(part.m)
+    out["pool_bits_equal"] = bool(np.array_equal(
+        np.asarray(want.bitstrings), np.asarray(got.bitstrings)))
+    out["pool_expectation_equal"] = bool(np.array_equal(
+        np.asarray(want.expectation), np.asarray(got.expectation)))
+    return out
+
+
 def check_sharded_qaoa():
     out = {}
     n = 10
@@ -455,6 +537,7 @@ def check_service_mesh():
 def main():
     checks = {
         "solve_pool": check_solve_pool,
+        "n_solvers": check_n_solvers,
         "sharded_qaoa": check_sharded_qaoa,
         "merge_sharded": check_merge_sharded,
         "engine_grad": check_engine_grad,

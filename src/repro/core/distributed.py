@@ -6,7 +6,9 @@ DESIGN.md §2:
 
 1. `solve_pool`       — solver-pool data parallelism: the vmapped subgraph
    batch is sharded across the `data` (and `pod`) axes. This is the paper's
-   "N_s QAOA solvers × T rounds" recast as SPMD.
+   "N_s QAOA solvers × T rounds" recast as SPMD. Its program is the one
+   pool program of `qaoa.solve_subgraph_batch_program`, which `core.solve`
+   runs over the first ``n_solvers`` devices.
 
 2. `sharded_qaoa`     — statevector tensor parallelism: one subproblem's
    2^n amplitudes sharded across the `model` axis. The transverse-field
@@ -34,13 +36,12 @@ DESIGN.md §2:
 
 All three go through `repro.compat` (portable shard_map + mesh handling)
 and are *cached compiled programs*: the jitted callable is built once per
-static configuration (config, mesh, axes), not per call, with buffer
-donation on backends that support it.
+static configuration (config, mesh, axes), not per call.
 """
 
 from __future__ import annotations
 
-import functools
+import dataclasses
 from typing import NamedTuple
 
 import jax
@@ -59,89 +60,22 @@ from repro.kernels import tuning
 # ---------------------------------------------------------------------------
 # 1. solver-pool data parallelism
 # ---------------------------------------------------------------------------
-@compat.cached_program
-def _solve_pool_program(
-    cfg: qaoa_mod.QAOAConfig, mesh: Mesh, axes: tuple, donate: bool,
-    impl: str,
-    tune: tuple,
-    has_lin: bool = False,
-):
-    # the per-shard `kernels.ops` dispatch is a trace-time choice, so
-    # `ops.using_implementation` only reaches the pool if each
-    # implementation gets its own compiled program; the keyed `impl` is
-    # re-asserted during tracing because jit traces lazily on first call,
-    # possibly outside the context the program was requested under. The
-    # `kernels.tuning` block-shape state is trace-time in the same way,
-    # so it is keyed and re-asserted alongside (DESIGN.md §2.7). `has_lin`
-    # keys the linear-terms (QUBO/MIS) variant; False compiles the exact
-    # Max-Cut program, keeping that path bit-identical.
-    spec = P(axes)
-
-    if has_lin:
-
-        def run(e, w, mk, l):
-            with ops.using_implementation(impl), tuning.using_state(tune):
-                return qaoa_mod.solve_subgraph_batch_linear(e, w, mk, cfg, l)
-
-        in_specs = (spec, spec, spec, spec)
-        donate_args = (0, 1, 2, 3)
-    else:
-
-        def run(e, w, mk):
-            with ops.using_implementation(impl), tuning.using_state(tune):
-                return qaoa_mod.solve_subgraph_batch(e, w, mk, cfg)
-
-        in_specs = (spec, spec, spec)
-        donate_args = (0, 1, 2)
-
-    sharded = compat.shard_map(
-        run,
-        mesh,
-        in_specs=in_specs,
-        out_specs=qaoa_mod.QAOAResult(spec, spec, spec, spec, spec),
-    )
-    # donate only when solve_pool owns the (freshly padded) batch arrays —
-    # donating caller-owned arrays would invalidate them behind its back
-    return compat.jit(sharded, donate_argnums=donate_args if donate else ())
-
-
 def solve_pool(edges, weights, masks, cfg: qaoa_mod.QAOAConfig, mesh: Mesh,
                axes=("data",), linears=None):
-    """Batched QAOA across the mesh: round-robin subgraphs over devices.
+    """Batched QAOA across the mesh: each device solves its share of rows.
 
-    Pads the batch to a multiple of the axis size (padding entries are
-    empty graphs) and strips the padding on return. ``linears``
-    (B, n_qubits) f32, optional, carries per-vertex diagonal terms
-    (QUBO/MIS buckets); ``None`` runs the unchanged Max-Cut program.
+    The pool program of `qaoa.solve_subgraph_batch_program` over
+    ``mesh``'s ``axes``: it pads the batch to a multiple of the axes'
+    size (padding entries are empty graphs) and strips the padding on
+    return. ``linears`` (B, n_qubits) f32, optional, carries per-vertex
+    diagonal terms (QUBO/MIS buckets); ``None`` runs the unchanged
+    Max-Cut program.
     """
-    axes = tuple(axes)
-    total = int(np.prod([mesh.shape[a] for a in axes]))
-    m = edges.shape[0]
-    m_pad = ((m + total - 1) // total) * total
-    pad = m_pad - m
-    if pad:
-        edges = jnp.concatenate(
-            [edges, jnp.zeros((pad,) + edges.shape[1:], edges.dtype)]
-        )
-        weights = jnp.concatenate(
-            [weights, jnp.zeros((pad,) + weights.shape[1:], weights.dtype)]
-        )
-        masks = jnp.concatenate([masks, jnp.ones((pad,), masks.dtype)])
-        if linears is not None:
-            linears = jnp.concatenate(
-                [linears, jnp.zeros((pad,) + linears.shape[1:], linears.dtype)]
-            )
-
-    # normalize the cache key on non-donating backends: donate=True and
-    # donate=False would otherwise compile byte-identical programs twice
-    donate = bool(pad) and compat.supports_donation()
-    program = _solve_pool_program(
-        cfg, mesh, axes, donate, ops.get_implementation(), tuning.state(),
-        linears is not None,
-    )
-    res = (program(edges, weights, masks) if linears is None
-           else program(edges, weights, masks, linears))
-    return jax.tree.map(lambda x: x[:m], res)
+    program = qaoa_mod.solve_subgraph_batch_program(
+        cfg, linears is not None, mesh=mesh, axes=tuple(axes))
+    if linears is None:
+        return program(edges, weights, masks)
+    return program(edges, weights, masks, linears)
 
 
 # ---------------------------------------------------------------------------
@@ -491,7 +425,8 @@ def solve_distributed(
             )
 
         # ---- stage 2: solver pool + oversized-subproblem routing ---------
-        qcfg = cfg.qaoa_config()
+        # the mesh, not `n_solvers`, places the pool here
+        qcfg = dataclasses.replace(cfg.qaoa_config(), n_solvers=1)
         small = [i for i, s in enumerate(part.sizes) if s <= device_cap]
         big = [i for i, s in enumerate(part.sizes) if s > device_cap]
         if big and not model_axis:
@@ -502,33 +437,33 @@ def solve_distributed(
             )
 
         bit_indices = np.zeros((part.m, cfg.top_k), dtype=np.int64)
+        shards = int(np.prod([mesh.shape[a] for a in data_axes]))
+        pool_mesh = mesh if data_axes else None
         with tr.span("solve_pool", m=part.m, n_small=len(small),
-                     n_big=len(big)) as spans["solve_pool"]:
+                     n_big=len(big), shards=shards,
+                     pad=-len(small) % shards) as spans["solve_pool"]:
             with tr.span("pool_pack") as spans["pool_pack"]:
                 if small:
-                    edges, weights, masks = qaoa_mod.pad_subgraph_arrays(
+                    arrays = qaoa_mod.pad_subgraph_arrays(
                         [part.subgraphs[i] for i in small], device_cap
                     )
-                    linears = (
-                        qaoa_mod.pad_linear_arrays(
+                    if has_lin:
+                        arrays += (qaoa_mod.pad_linear_arrays(
                             [sub_lins[i] for i in small], device_cap
-                        )
-                        if has_lin else None
-                    )
-            if small:
-                with tr.span("pool_run") as spans["pool_run"]:
-                    if data_axes:
-                        res = solve_pool(edges, weights, masks, qcfg, mesh,
-                                         axes=data_axes, linears=linears)
-                    elif has_lin:  # model-only mesh: single-device pool
-                        res = qaoa_mod.solve_subgraph_batch_program(
-                            qcfg, has_linear=True
-                        )(edges, weights, masks, linears)
-                    else:
-                        res = qaoa_mod.solve_subgraph_batch_program(qcfg)(
-                            edges, weights, masks
-                        )
-                    bit_indices[small] = np.asarray(res.bitstrings)
+                        ),)
+                with tr.span("pool_place") as spans["pool_place"]:
+                    if small:
+                        arrays = qaoa_mod.place_pool_arrays(
+                            arrays, qcfg, mesh=pool_mesh, axes=data_axes)
+            with tr.span("pool_run") as spans["pool_run"]:
+                if small:
+                    res = jax.block_until_ready(
+                        qaoa_mod.solve_subgraph_batch_program(
+                            qcfg, has_lin, mesh=pool_mesh, axes=data_axes,
+                        )(*arrays))
+                with tr.span("pool_fetch") as spans["pool_fetch"]:
+                    if small:
+                        bit_indices[small] = np.asarray(res.bitstrings)
             # oversized subproblems: grouped by qubit count and run as
             # stacked batches through one cached sharded-engine program per
             # n (edge arrays padded with exact-no-op zero rows) — instead

@@ -33,7 +33,7 @@ from repro.obs import trace as trace_mod
 class ParaQAOAConfig:
     # hardware-dependent (paper: N_s solvers × N qubits)
     n_qubits: int = 14  # N — per-solver qubit budget (26 on the paper's GPUs)
-    n_solvers: int = 1  # N_s — concurrent solver instances (mesh data-axis size)
+    n_solvers: int = 1  # N_s — the solver pool's `data` mesh: the first N_s devices
     # tunable (paper: K, L)
     top_k: int = 2  # K — candidates kept per subgraph
     merge_level: int = 2  # L — frontier materialization level (distributed merge)
@@ -59,6 +59,7 @@ class ParaQAOAConfig:
             learning_rate=self.learning_rate,
             ramp_delta=self.ramp_delta,
             top_k=self.top_k,
+            n_solvers=self.n_solvers,
         )
 
 
@@ -79,6 +80,8 @@ TIMING_SPANS = (
     ("partition_s", "partition"),
     ("solve_s", "solve_pool"),
     ("pool_pack_s", "pool_pack"),
+    ("pool_place_s", "pool_place"),
+    ("pool_fetch_s", "pool_fetch"),
     ("merge_s", "merge"),
     ("merge_plan_s", "merge_plan"),
     ("merge_scan_s", "merge_scan"),
@@ -176,26 +179,30 @@ def solve(
             sub_lins = split_linear(part, prob.linear) if has_lin else None
 
         # ---- stage 2: parallelized QAOA execution ------------------------
-        with tr.span("solve_pool", m=part.m,
-                     n_qubits=cfg.n_qubits) as spans["solve_pool"]:
+        # the pool runs on one device, or with n_solvers > 1 as one
+        # program over a `data` mesh of that many devices (paper: N_s
+        # solvers); `pad` empty subgraphs even the rows out over it
+        with tr.span("solve_pool", m=part.m, n_qubits=cfg.n_qubits,
+                     shards=cfg.n_solvers,
+                     pad=-part.m % cfg.n_solvers) as spans["solve_pool"]:
             qcfg = cfg.qaoa_config()
             with tr.span("pool_pack") as spans["pool_pack"]:
-                edges, weights, masks = qaoa_mod.pad_subgraph_arrays(
-                    part.subgraphs, qcfg.n_qubits
-                )
+                arrays = qaoa_mod.pad_subgraph_arrays(part.subgraphs,
+                                                      qcfg.n_qubits)
                 if has_lin:
-                    linears = qaoa_mod.pad_linear_arrays(sub_lins,
-                                                         qcfg.n_qubits)
+                    arrays += (qaoa_mod.pad_linear_arrays(sub_lins,
+                                                          qcfg.n_qubits),)
+                with tr.span("pool_place") as spans["pool_place"]:
+                    arrays = qaoa_mod.place_pool_arrays(arrays, qcfg)
             with tr.span("pool_run") as spans["pool_run"]:
                 if has_lin:
-                    result = qaoa_mod.solve_subgraph_batch_program(
-                        qcfg, has_linear=True
-                    )(edges, weights, masks, linears)
+                    program = qaoa_mod.solve_subgraph_batch_program(
+                        qcfg, has_linear=True)
                 else:
-                    result = qaoa_mod.solve_subgraph_batch_program(qcfg)(
-                        edges, weights, masks
-                    )
-                bit_indices = np.asarray(result.bitstrings)  # (M, K)
+                    program = qaoa_mod.solve_subgraph_batch_program(qcfg)
+                result = jax.block_until_ready(program(*arrays))
+                with tr.span("pool_fetch") as spans["pool_fetch"]:
+                    bit_indices = np.asarray(result.bitstrings)  # (M, K)
 
         # ---- stage 3: level-aware parallel merge -------------------------
         with tr.span("merge", m=part.m) as spans["merge"]:

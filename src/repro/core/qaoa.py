@@ -17,10 +17,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import compat
 from repro.core import engine
@@ -38,6 +40,7 @@ class QAOAConfig:
     ramp_delta: float = 0.75  # linear-ramp initialization scale
     top_k: int = 4  # paper's K (Selective Distribution Exploration)
     mixer_group: int = 7  # qubits per fused mixer matmul (7 → 128×128)
+    n_solvers: int = 1  # paper's N_s: the pool's `data` mesh size (1: one device)
 
 
 class QAOAResult(NamedTuple):
@@ -137,9 +140,11 @@ solve_subgraph_batch_linear = jax.vmap(solve_subgraph, in_axes=(0, 0, 0, None, 0
 
 @compat.cached_program
 def _solve_subgraph_batch_program(
-    cfg: QAOAConfig, impl: str, tune: tuple, has_lin: bool = False
+    cfg: QAOAConfig, impl: str, tune: tuple, has_lin: bool = False,
+    mesh=None, axes: tuple = ("data",),
 ):
-    """Impl- and tuning-keyed builder behind `solve_subgraph_batch_program`.
+    """Impl- and tuning-keyed builder behind `solve_subgraph_batch_program`,
+    the one builder of the solver pool's program.
 
     The `kernels.ops` dispatch reads the active implementation at
     *trace* time, so two impls must map to two compiled programs for
@@ -154,6 +159,13 @@ def _solve_subgraph_batch_program(
     ``has_lin`` selects the linear-terms variant (QUBO/MIS buckets, 4th
     input array); the False key compiles the exact Max-Cut program of the
     linear-free solver, keeping that path bit-identical.
+
+    With a ``mesh`` the same batch runs as one `shard_map` over its
+    ``axes`` (the paper's N_s solvers as SPMD, DESIGN.md §2.4): inside
+    the jitted program the batch is padded with empty subgraphs (mask 1,
+    no edges) to a multiple of the axes' size, each device solves its
+    rows, and the padding is stripped, so the result has as many rows as
+    the input.
     """
 
     if has_lin:
@@ -168,26 +180,92 @@ def _solve_subgraph_batch_program(
             with ops.using_implementation(impl), tuning.using_state(tune):
                 return solve_subgraph_batch(e, w, m, cfg)
 
-    return jax.jit(run)
+    if mesh is None:
+        return jax.jit(run)
+
+    shards = math.prod(mesh.shape[a] for a in axes)
+    spec = P(axes)
+    sharded = compat.shard_map(
+        run, mesh, in_specs=(spec,) * (4 if has_lin else 3),
+        out_specs=QAOAResult(*(spec,) * len(QAOAResult._fields)),
+    )
+    # filler rows: no edges, zero weights, mask 1, no linear terms
+    fill = (0, 0, 1, 0)
+
+    def run_pool(*arrays):
+        m = arrays[0].shape[0]
+        pad = -m % shards
+        if pad:
+            arrays = tuple(
+                jnp.concatenate([a, jnp.full((pad,) + a.shape[1:], f,
+                                             a.dtype)])
+                for a, f in zip(arrays, fill))
+        return jax.tree.map(lambda x: x[:m], sharded(*arrays))
+
+    return jax.jit(run_pool)
 
 
-def solve_subgraph_batch_program(cfg: QAOAConfig, has_linear: bool = False):
+def pool_mesh(cfg: QAOAConfig, mesh=None):
+    """The mesh the pool runs over: ``mesh`` where given, else with
+    ``cfg.n_solvers`` > 1 the `data` mesh of the first ``n_solvers``
+    devices, else None (one device)."""
+    if mesh is not None or cfg.n_solvers <= 1:
+        return mesh
+    devices = jax.devices()
+    if cfg.n_solvers > len(devices):
+        raise ValueError(
+            f"n_solvers={cfg.n_solvers} needs {cfg.n_solvers} devices, "
+            f"but {len(devices)} are visible")
+    return compat.make_mesh((cfg.n_solvers,), ("data",),
+                            devices=devices[:cfg.n_solvers])
+
+
+def solve_subgraph_batch_program(cfg: QAOAConfig, has_linear: bool = False,
+                                 mesh=None, axes=("data",)):
     """Cached whole-batch jit of `solve_subgraph_batch` for one config.
 
     The end-to-end drivers run this instead of the eager vmap: one fused
     XLA program per static config (~1.7x faster on CPU), and — because the
-    distributed `solve_pool` wraps the *same* jitted computation in
-    shard_map — the single-device and pool-parallel paths produce
-    bit-identical candidates (XLA's eager op-by-op dispatch rounds
-    differently from the fused program; the default 30 Adam steps
-    (``QAOAConfig.opt_steps``) on a non-convex landscape amplify that
-    last-ulp difference into different top-k picks). The underlying
-    cache keys on (config, active `kernels.ops` implementation, active
-    `kernels.tuning` block-shape state, linear-terms variant).
+    pool over a mesh wraps the *same* batch computation in shard_map —
+    the single-device and pool-parallel paths produce bit-identical
+    candidates (XLA's eager op-by-op dispatch rounds differently from the
+    fused program; the default 30 Adam steps (``QAOAConfig.opt_steps``)
+    on a non-convex landscape amplify that last-ulp difference into
+    different top-k picks). The underlying cache keys on (config, active
+    `kernels.ops` implementation, active `kernels.tuning` block-shape
+    state, linear-terms variant), and on the mesh and axes where there
+    is one.
+
+    The program takes (edges, weights, masks[, linears]) with one row a
+    subgraph and returns a `QAOAResult` with as many rows. It runs on
+    one device when ``cfg.n_solvers`` is 1, else over the `data` mesh of
+    the first ``n_solvers`` devices (`pool_mesh`); an explicit
+    ``mesh`` runs it over that mesh's ``axes`` instead.
     """
-    return _solve_subgraph_batch_program(
-        cfg, ops.get_implementation(), tuning.state(), bool(has_linear)
-    )
+    key = (cfg, ops.get_implementation(), tuning.state(), bool(has_linear))
+    mesh = pool_mesh(cfg, mesh)
+    if mesh is None:
+        return _solve_subgraph_batch_program(*key)
+    return _solve_subgraph_batch_program(*key, mesh, tuple(axes))
+
+
+def place_pool_arrays(arrays, cfg: QAOAConfig, mesh=None, axes=("data",)):
+    """Put the packed batch where `solve_subgraph_batch_program(cfg, ...,
+    mesh, axes)` runs, and wait for the transfer to end.
+
+    One device: the default one. A mesh: each device its own rows where
+    the batch divides evenly over ``axes``; otherwise every device the
+    whole batch, from which the program slices its rows after padding.
+    """
+    mesh = pool_mesh(cfg, mesh)
+    if mesh is None:
+        placed = jax.device_put(tuple(arrays))
+    else:
+        shards = math.prod(mesh.shape[a] for a in axes)
+        rows = arrays[0].shape[0]
+        spec = P(tuple(axes)) if rows % shards == 0 else P()
+        placed = jax.device_put(tuple(arrays), NamedSharding(mesh, spec))
+    return jax.block_until_ready(placed)
 
 
 def index_to_bits(indices: jnp.ndarray, n: int) -> jnp.ndarray:
@@ -200,12 +278,13 @@ def pad_subgraph_arrays(
     subgraphs, n_qubits: int, e_pad: int | None = None,
     n_rows: int | None = None,
 ):
-    """Stack per-subgraph (edges, weights, real_mask) into batch arrays.
+    """Stack per-subgraph (edges, weights, real_mask) into host batch
+    arrays (NumPy; `place_pool_arrays` puts them on the device).
 
     ``n_rows`` pads the batch dimension with empty-graph filler rows
-    (mask 1, no edges — the same convention `solve_pool` pads with), the
-    shape-stable packing the serve-side scheduler relies on (one source
-    of truth for the DESIGN.md §6.1 parity contract).
+    (mask 1, no edges — the same convention the pool program pads a mesh
+    batch with), the shape-stable packing the serve-side scheduler relies
+    on (one source of truth for the DESIGN.md §6.1 parity contract).
     """
     import numpy as np
 
@@ -224,12 +303,12 @@ def pad_subgraph_arrays(
         edges[i, :m] = np.asarray(g.edges)
         weights[i, :m] = np.asarray(g.weights)
         masks[i] = (1 << g.n) - 1
-    return jnp.asarray(edges), jnp.asarray(weights), jnp.asarray(masks)
+    return edges, weights, masks
 
 
 def pad_linear_arrays(linears, n_qubits: int, n_rows: int | None = None):
     """Stack per-subgraph linear-term vectors into one (rows, n_qubits)
-    float32 batch array, zero-padded on both axes — the companion of
+    float32 host array, zero-padded on both axes — the companion of
     `pad_subgraph_arrays` for QUBO/MIS buckets (padding qubits and filler
     rows contribute h = 0, so they stay objective-neutral)."""
     import numpy as np
@@ -242,4 +321,4 @@ def pad_linear_arrays(linears, n_qubits: int, n_rows: int | None = None):
         l = np.asarray(l, dtype=np.float32)
         assert l.shape[0] <= n_qubits, (l.shape[0], n_qubits)
         out[i, : l.shape[0]] = l
-    return jnp.asarray(out)
+    return out

@@ -1,6 +1,7 @@
 """Multi-device (8 fake CPU devices) equivalence tests, run in subprocesses
 so the main pytest process keeps its single-device view."""
 
+import functools
 import json
 import os
 import subprocess
@@ -33,6 +34,35 @@ def test_solve_pool_matches_single_device():
     res = _run_check("solve_pool")
     assert res["bitstrings_equal"], res
     assert res["exp_close"], res
+
+
+@functools.lru_cache(maxsize=None)
+def _n_solvers_check() -> dict:
+    return _run_check("n_solvers")
+
+
+@pytest.mark.parametrize("case", ["maxcut_even", "maxcut_pad", "qubo_even",
+                                  "qubo_pad"])
+def test_solve_n_solvers_matches_one_device(case):
+    """`solve` with the pool over a `data` mesh of 4 devices gives the
+    one-device pool's candidates, assignment and value bit for bit, with
+    M a multiple of 4 and not (one padding row), with and without linear
+    terms."""
+    res = _n_solvers_check()
+    assert res[case + "_m"] % 4 == (0 if case.endswith("even") else 3), res
+    for what in ("bits", "assignment", "value"):
+        assert res[f"{case}_{what}_equal"], (what, res)
+
+
+def test_solve_n_solvers_above_device_count_raises():
+    msg = _n_solvers_check()["too_many_solvers_error"]
+    assert "16" in msg and "8" in msg, msg
+
+
+def test_solve_pool_pads_to_its_mesh_and_matches():
+    res = _n_solvers_check()
+    assert res["pool_m"] % 4, res
+    assert res["pool_bits_equal"] and res["pool_expectation_equal"], res
 
 
 def test_sharded_statevector_matches_single_device():

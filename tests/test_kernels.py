@@ -389,16 +389,12 @@ def test_solve_pool_program_cache_keys_on_implementation():
 
     qcfg = qaoa_mod.QAOAConfig(n_qubits=6, p_layers=2, opt_steps=4, top_k=2)
     mesh = compat.make_mesh((1,), ("data",))
-    donate = compat.supports_donation()
     off = tuning.state()
-    p_x = dist._solve_pool_program(qcfg, mesh, ("data",), donate, "xla", off)
-    p_i = dist._solve_pool_program(
-        qcfg, mesh, ("data",), donate, "pallas_interpret", off
-    )
+    build = qaoa_mod._solve_subgraph_batch_program
+    p_x = build(qcfg, "xla", off, False, mesh, ("data",))
+    p_i = build(qcfg, "pallas_interpret", off, False, mesh, ("data",))
     assert p_x is not p_i
-    assert dist._solve_pool_program(
-        qcfg, mesh, ("data",), donate, "xla", off
-    ) is p_x
+    assert build(qcfg, "xla", off, False, mesh, ("data",)) is p_x
 
     g = _graph(16, 0.4, seed=23)
     part = partition_for_solver(g, 6)

@@ -73,6 +73,24 @@ def test_solve_leaf_spans_nest_and_sum_to_their_stage():
         [s.as_dict() for s in tr.spans]) == []
 
 
+def test_pool_place_and_fetch_are_leaves_of_pack_and_run():
+    """`pool_place` (the packed batch onto the device, through the
+    transfer's end) nests in `pool_pack`, `pool_fetch` (the host copy of
+    the candidates, once the device result is ready) in `pool_run`; the
+    `solve_pool` span names the devices and the padding rows."""
+    tr, out = _traced_solve()
+    by_id = {s.span_id: s for s in tr.spans}
+    for leaf, parent in (("pool_place", "pool_pack"),
+                         ("pool_fetch", "pool_run")):
+        found = [s for s in tr.spans if s.name == leaf]
+        assert len(found) == 1, (leaf, len(found))
+        assert by_id[found[0].parent_id].name == parent
+        assert out.timings[leaf + "_s"] == found[0].duration_s
+    assert out.timings["pool_place_s"] <= out.timings["pool_pack_s"]
+    pool = next(s for s in tr.spans if s.name == "solve_pool")
+    assert pool.attrs["shards"] == 1 and pool.attrs["pad"] == 0
+
+
 def test_untraced_solve_reports_the_same_timing_keys():
     _, traced = _traced_solve()
     _, plain = _traced_solve(record=False)

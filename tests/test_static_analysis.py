@@ -154,43 +154,52 @@ def test_cache_key_accepts_param_tuning_state():
 def test_cache_key_regression_solve_pool_program():
     """Acceptance criterion: stripping the PR 5 fix (the `impl` re-assert
     inside the cached pool/statevector builders) out of the *real*
-    distributed.py must re-raise the finding — exercising the
-    cross-module call graph through qaoa's `solve_subgraph_batch` vmap
-    alias. The unmodified sources must stay clean."""
+    sources must re-raise the finding — for the one pool-program builder
+    in qaoa.py (the solver pool `solve` and `solve_pool` run, one device
+    or a mesh) and the statevector builder in distributed.py, whose
+    dispatch reaches the kernels through the engine. The unmodified
+    sources must stay clean."""
     paths = [
         "src/repro/core/distributed.py", "src/repro/core/qaoa.py",
         "src/repro/core/engine.py", "src/repro/core/merge.py",
         "src/repro/kernels/ops.py", "src/repro/compat.py",
     ]
+    builders = ("src/repro/core/qaoa.py", "src/repro/core/distributed.py")
     sources = {p: _src(p) for p in paths}
     assert run_on_sources(sources, rules=["cache-key"]).findings == []
 
-    dist_src = sources["src/repro/core/distributed.py"]
-    degraded, n_subs = re.subn(
-        r"with ops\.using_implementation\(impl\)"
-        r"(?:, tuning\.using_state\(tune\))?:",
-        "if True:", dist_src,
-    )
-    assert n_subs >= 2, "expected the keyed builders in distributed.py"
-    sources["src/repro/core/distributed.py"] = degraded
-    rep = run_on_sources(sources, rules=["cache-key"])
+    degraded, total = dict(sources), 0
+    for p in builders:
+        degraded[p], n_subs = re.subn(
+            r"with ops\.using_implementation\(impl\)"
+            r"(?:, tuning\.using_state\(tune\))?:",
+            "if True:", sources[p],
+        )
+        assert n_subs >= 1, f"expected a keyed builder in {p}"
+        total += n_subs
+    assert total >= 3
+    rep = run_on_sources(degraded, rules=["cache-key"])
     flagged = {f.symbol for f in rep.findings}
-    assert "_solve_pool_program" in flagged, [f.render() for f in rep.findings]
+    assert "_solve_subgraph_batch_program" in flagged, [
+        f.render() for f in rep.findings]
     assert "_sharded_qaoa_program" in flagged
 
     # variant: delete `impl` from the cache signature but keep the
     # re-assert — now the with-block reads a value the lru key cannot
     # see, the other half of the same bug
-    unsigned, n_subs = re.subn(
-        r"(?m)^(\s*)impl: str,?$|,\s*impl: str(?=\s*\))", r"\1", dist_src
-    )
-    assert n_subs >= 2, "expected impl params in the builder signatures"
-    sources["src/repro/core/distributed.py"] = unsigned
-    rep = run_on_sources(sources, rules=["cache-key"])
-    assert any(
-        f.rule == "cache-key" and "_solve_pool_program" in (f.symbol or "")
-        for f in rep.findings
-    ), [f.render() for f in rep.findings]
+    unsigned = dict(sources)
+    for p in builders:
+        unsigned[p], n_subs = re.subn(
+            r"(?m)^(\s*)impl: str,?$|,\s*impl: str(?=\s*\))|\bimpl: str, ",
+            r"\1", sources[p],
+        )
+        assert n_subs >= 1, f"expected an impl param in {p}"
+    rep = run_on_sources(unsigned, rules=["cache-key"])
+    flagged = " ".join(f.symbol or "" for f in rep.findings
+                       if f.rule == "cache-key")
+    assert "_solve_subgraph_batch_program" in flagged, [
+        f.render() for f in rep.findings]
+    assert "_sharded_qaoa_program" in flagged
 
 
 # ---------------------------------------------------------- dispatch-purity --

@@ -21,7 +21,6 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro import compat
-from repro.core import distributed as dist
 from repro.core import qaoa as qaoa_mod
 from repro.kernels import cutvals, fused_layer, mixer, phase, tuning
 
@@ -154,11 +153,28 @@ def test_solve_pool_compiles_on_four_chip_mesh(topo):
     data = NamedSharding(mesh, P("data"))
     cfg = qaoa_mod.QAOAConfig(n_qubits=N)
     m_pad = -(-M_SUBGRAPHS // 4) * 4
-    program = dist._solve_pool_program(cfg, mesh, ("data",), False, "pallas",
-                                       tuning.state(), False)
+    program = qaoa_mod._solve_subgraph_batch_program(
+        cfg, "pallas", tuning.state(), False, mesh, ("data",))
     compiled = program.lower(*_solver_structs(m_pad, data)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert "all-gather" not in compiled.as_text()  # each chip keeps its rows
+
+
+def test_four_solver_pool_of_17_qubits_fits_each_chip(topo):
+    """The pool of the four-chip deployment (`n_solvers` 4): 1,000
+    subgraphs of 17 qubits, 250 a chip, within the solver budget on each
+    chip, where one chip would need all 1,000."""
+    mesh = compat.make_mesh((4,), ("data",), devices=topo.devices)
+    cfg = qaoa_mod.QAOAConfig(n_qubits=17)
+    program = qaoa_mod._solve_subgraph_batch_program(
+        cfg, "pallas", tuning.state(), False, mesh, ("data",))
+    structs = _solver_structs(1000, NamedSharding(mesh, P("data")))
+    compiled = program.lower(*structs).compile()
+    assert "all-gather" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert total < SOLVER_BUDGET_BYTES, mem
 
 
 def test_refine_sweeps_compile_for_v5e(one_chip):
