@@ -28,6 +28,9 @@ class Graph:
       edges: (E_pad, 2) int32 vertex indices, padding rows are (0, 0).
       weights: (E_pad,) float32, zero on padding rows.
       n_edges: true (unpadded) edge count, static python int.
+
+    ``edges`` and ``weights`` may be host (NumPy) arrays, as the
+    partition's subgraphs are; `from_edges` puts them on the device.
     """
 
     n: int

@@ -4,9 +4,10 @@ The CPP algorithm splits vertex indices into M contiguous ranges where
 adjacent ranges overlap in exactly one vertex (the "shared node"). The base
 partition size is s = floor(|V|/M) - 1 and range i covers
 [i*s, i*s + s + 1), with the last range absorbing the remainder — a direct
-transcription of Alg. 1. Complexity is O(|V| + |E|): one pass to slice the
-ranges, one pass over edges per subgraph extraction (done as one global
-pass here).
+transcription of Alg. 1. Building the subgraphs is one pass over the
+edges and one stable sort of their subgraph keys, O(|E| log |E|) at worst
+(a radix sort of 16-bit keys below 65,536 subgraphs). The subgraphs are
+rows of two packed host arrays; nothing is uploaded to a device.
 
 The partition output also records the *inter-partition* edges (the edges
 dropped from every subgraph), which the merge phase re-scores globally —
@@ -21,6 +22,7 @@ from typing import List
 import numpy as np
 
 from repro.core.graph import Graph
+from repro.obs import trace as trace_mod
 
 
 @dataclasses.dataclass(frozen=True)
@@ -28,7 +30,8 @@ class Partition:
     """Result of partitioning a graph into a chain of subgraphs.
 
     Attributes:
-      subgraphs: list of induced subgraphs, each with *local* vertex labels.
+      subgraphs: list of induced subgraphs, each with *local* vertex labels;
+        their leaves are NumPy rows of one (M, E_pad) packed host batch.
       ranges: list of (lo, hi) global vertex ranges per subgraph;
         ranges[i].hi - 1 == ranges[i+1].lo is the shared vertex.
       sizes: number of vertices per subgraph.
@@ -99,19 +102,12 @@ def balanced_ranges(n: int, m: int) -> List[tuple]:
     return ranges
 
 
-def _contiguous_ranges(n: int, m: int, exact_alg1: bool = False) -> List[tuple]:
-    return alg1_ranges(n, m) if exact_alg1 else balanced_ranges(n, m)
-
-
-def connectivity_preserving_partition(
-    graph: Graph, m: int, pad_edges: bool = True
-) -> Partition:
+def connectivity_preserving_partition(graph: Graph, m: int) -> Partition:
     """Paper Alg. 1: contiguous ranges with one shared vertex per boundary."""
-    ranges = _contiguous_ranges(graph.n, m)
-    return _build_partition(graph, ranges, pad_edges)
+    return _build_partition(graph, balanced_ranges(graph.n, m))
 
 
-def random_partition(graph: Graph, m: int, seed: int, pad_edges: bool = True) -> Partition:
+def random_partition(graph: Graph, m: int, seed: int) -> Partition:
     """QAOA²-style randomized partitioning (baseline): random vertex order,
     then contiguous ranges over the shuffled labels. Returned subgraphs use
     the same chain/shared-vertex contract as CPP so the merge phase is
@@ -123,9 +119,7 @@ def random_partition(graph: Graph, m: int, seed: int, pad_edges: bool = True) ->
     e = np.asarray(graph.edges)[: graph.n_edges]
     w = np.asarray(graph.weights)[: graph.n_edges]
     relabelled = Graph.from_edges(graph.n, inv[e], w, pad_to=graph.edges.shape[0])
-    ranges = _contiguous_ranges(graph.n, m)
-    part = _build_partition(relabelled, ranges, pad_edges)
-    return part
+    return _build_partition(relabelled, balanced_ranges(graph.n, m))
 
 
 def partition_for_solver(graph: Graph, max_qubits: int) -> Partition:
@@ -146,39 +140,59 @@ def partition_for_solver(graph: Graph, max_qubits: int) -> Partition:
     return part
 
 
-def _build_partition(graph: Graph, ranges: List[tuple], pad_edges: bool) -> Partition:
+def _build_partition(graph: Graph, ranges: List[tuple]) -> Partition:
+    """Assign every edge to the subgraph whose range holds both endpoints,
+    in one pass. Ranges are contiguous, sorted, and any two share at most
+    one vertex, so an edge (a, b), a <= b, can only lie in the last range
+    with lo <= a, and does iff b < hi there; else it is an inter-partition
+    edge. A self-loop on a shared vertex lies in both ranges around it, so
+    it is counted twice. Each subgraph's edges, relabelled to local vertex
+    labels and zero-padded to the largest subgraph's count, keep the
+    graph's edge order, as do the inter-partition edges."""
     e = np.asarray(graph.edges)[: graph.n_edges]
     w = np.asarray(graph.weights)[: graph.n_edges]
+    m = len(ranges)
+    lo = np.asarray([r[0] for r in ranges], dtype=np.int32)
+    hi = np.asarray([r[1] for r in ranges], dtype=np.int32)
 
-    subgraphs: List[Graph] = []
-    sizes: List[int] = []
-    covered = np.zeros(e.shape[0], dtype=bool)
+    a = np.minimum(e[:, 0], e[:, 1])
+    b = np.maximum(e[:, 0], e[:, 1])
+    # the last range starting at or before each vertex, looked up per edge
+    last = np.searchsorted(lo, np.arange(graph.n), side="right") - 1
+    i = last[a]
+    key = np.where(b < hi[i], i, m).astype(np.min_scalar_type(m))
+    # a self-loop on the shared vertex lo[i] also lies in range i - 1: it
+    # enters the sort twice, its first copy keyed i - 1, so a stable sort
+    # keeps every subgraph's edges in the graph's order
+    twice = (a == b) & (a == lo[i]) & (i > 0)
+    copies = 1 + twice
+    src = np.repeat(np.arange(e.shape[0]), copies)
+    key = np.repeat(key, copies)
+    key[(np.cumsum(copies) - copies)[twice]] -= 1
 
-    # One O(|E|) pass per membership test, vectorised in numpy.
-    sub_edge_lists = []
-    for lo, hi in ranges:
-        inside = (e[:, 0] >= lo) & (e[:, 0] < hi) & (e[:, 1] >= lo) & (e[:, 1] < hi)
-        covered |= inside
-        sub_edge_lists.append((lo, hi, e[inside] - lo, w[inside]))
-        sizes.append(hi - lo)
-
-    # Shared-vertex edges live in *both* adjacent subgraphs only if both
-    # endpoints sit in the overlap — impossible for distinct endpoints, so
-    # each intra edge belongs to exactly one subgraph except edges touching
-    # the shared vertex, which the (lo, hi) window assigns uniquely. An edge
-    # between the two vertices adjacent to a boundary shared vertex can be
-    # in neither — those fall into inter_edges below.
-    pad = max(max((el.shape[0] for _, _, el, _ in sub_edge_lists), default=1), 1)
-    if not pad_edges:
-        pad = None
-    for lo, hi, el, wl in sub_edge_lists:
-        subgraphs.append(Graph.from_edges(hi - lo, el, wl, pad_to=pad))
-
-    inter = ~covered
+    order = np.argsort(key, kind="stable")
+    key, src = key[order], src[order]
+    counts = np.bincount(key, minlength=m + 1)
+    e_pad = max(int(counts[:m].max()), 1)
+    first = np.cumsum(counts) - counts
+    inside = key < m
+    sub, idx = key[inside].astype(np.intp), src[inside]
+    slot = np.arange(sub.shape[0]) - first[sub]
+    edges = np.zeros((m, e_pad, 2), dtype=np.int32)
+    weights = np.zeros((m, e_pad), dtype=np.float32)
+    edges[sub, slot] = e[idx] - lo[sub, None]
+    weights[sub, slot] = w[idx]
+    inter = src[~inside]
+    trace_mod.get_tracer().annotate(m=m, e_pad=e_pad,
+                                    inter_edges=int(inter.shape[0]))
     return Partition(
-        subgraphs=subgraphs,
+        subgraphs=[
+            Graph(n=h - l, edges=edges[j], weights=weights[j],
+                  n_edges=int(counts[j]))
+            for j, (l, h) in enumerate(ranges)
+        ],
         ranges=list(ranges),
-        sizes=sizes,
+        sizes=[h - l for l, h in ranges],
         inter_edges=e[inter].astype(np.int32),
         inter_weights=w[inter].astype(np.float32),
         graph=graph,

@@ -91,6 +91,19 @@ def test_pool_place_and_fetch_are_leaves_of_pack_and_run():
     assert pool.attrs["shards"] == 1 and pool.attrs["pad"] == 0
 
 
+def test_partition_span_carries_the_partition_counts():
+    """`_build_partition` annotates the open `partition` span with its
+    subgraphs, their padded edge count and the edges left to the merge."""
+    from repro.core.partition import partition_for_solver
+
+    tr, _ = _traced_solve()
+    (span,) = [s for s in tr.spans if s.name == "partition"]
+    part = partition_for_solver(_graph(), CFG.n_qubits)
+    assert span.attrs["m"] == part.m > 1
+    assert span.attrs["e_pad"] == part.subgraphs[0].edges.shape[0]
+    assert span.attrs["inter_edges"] == part.inter_edges.shape[0] > 0
+
+
 def test_untraced_solve_reports_the_same_timing_keys():
     _, traced = _traced_solve()
     _, plain = _traced_solve(record=False)
